@@ -12,6 +12,8 @@ def main() -> None:
                     help="smaller datasets (CI-scale)")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import bench_table5, bench_construction, bench_sweeps, bench_kernels
 
     print("name,us_per_call,derived")

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.api import QueryBatch, QuerySpec
+from repro.compile_cache import enable_compile_cache
 from repro.serve import AggregateService
 
 
@@ -65,6 +66,7 @@ def main():
                     help="also time mixed-aggregate QueryBatch dispatch")
     args = ap.parse_args()
 
+    enable_compile_cache()
     srv = AggregateService(backend=args.backend)
     rng = np.random.default_rng(0)
     stats = {k: [] for k in ("count", "max", "count2d", "sum2d", "max2d")}

@@ -31,6 +31,8 @@ are now internal machinery behind this facade.
 from __future__ import annotations
 
 import dataclasses
+import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple, Union
 
 import jax
@@ -44,7 +46,7 @@ from ..engine import (DynamicEngine, DynamicEngine2D, LsmEngine,
                       WindowEngine, build_plan, build_plan_2d, execute,
                       execute_quantile, fused_executor,
                       fused_quantile_executor)
-from ..kernels.poly_eval import DEFAULT_BQ
+from ..kernels.poly_eval import DEFAULT_BQ, resolve_interpret
 from .budget import ErrorBudget
 from .spec import (DEFAULT_REL, KIND_OF_AGG, QueryBatch, QuerySpec,
                    TableSpec)
@@ -97,8 +99,10 @@ jax.tree_util.register_pytree_node(
 class _Table:
     """One fitted table: the spec plus whichever execution stack it needs."""
 
+    build_seconds: float = 0.0   # wall time of this table's construction
+
     def __init__(self, name: str, spec: TableSpec, data, *, backend: str,
-                 interpret: bool, bq: int, min_bucket: int):
+                 interpret: Optional[bool], bq: int, min_bucket: int):
         self.name = name
         self.spec = spec
         self.dyn = None
@@ -143,8 +147,8 @@ class _Table:
                 self._static_plan = build_plan_2d(idx)
             if spec.shards is not None:
                 self.sharded = ShardedEngine2D(spec.shards,
-                                               min_bucket=min_bucket)
-                self.sharded.shard(self.plan)   # warm the partition cache
+                                               min_bucket=min_bucket,
+                                               interpret=interpret)
         else:
             keys, meas = data
             keys = np.asarray(keys, np.float64)
@@ -171,8 +175,19 @@ class _Table:
                 self._static_plan = build_plan(idx)
             if spec.shards is not None:
                 self.sharded = ShardedEngine(spec.shards,
-                                             min_bucket=min_bucket)
-                self.sharded.shard(self.plan)   # warm the partition cache
+                                             min_bucket=min_bucket,
+                                             interpret=interpret)
+
+    def place(self, device) -> None:
+        """Commit the serving state, built on the host, to ``device``; a
+        sharded table then partitions the placed plan over its mesh."""
+        if self._static_plan is not None:
+            self._static_plan = jax.device_put(self._static_plan, device)
+        for engine in (self.dyn, self.win):
+            if engine is not None:
+                engine.place(device)
+        if self.sharded is not None:
+            self.sharded.shard(self.plan)   # warm the partition cache
 
     @property
     def plan(self):
@@ -215,7 +230,7 @@ class PolyFit:
     """A fitted PolyFit session — construct with :meth:`fit`."""
 
     def __init__(self, tables: Dict[str, _Table], *, backend: str,
-                 interpret: bool, bq: int, min_bucket: int):
+                 interpret: Optional[bool], bq: int, min_bucket: int):
         self._tables = tables
         self.backend = backend
         self.interpret = interpret
@@ -226,20 +241,24 @@ class PolyFit:
 
     @classmethod
     def fit(cls, datasets: Mapping, specs: Mapping[str, TableSpec], *,
-            backend: str = "xla", interpret: bool = True,
+            backend: str = "xla", interpret: Optional[bool] = None,
             bq: int = DEFAULT_BQ, min_bucket: int = 64) -> "PolyFit":
         """Build one index per named table and return the query session.
 
         ``datasets`` maps table name -> data: a bare key array (COUNT),
         ``(keys, measures)`` for SUM/MAX/MIN, ``(xs, ys)`` for 2-key COUNT.
         ``specs`` maps the same names to ``TableSpec``s; the spec's
-        ``ErrorBudget`` is the only source of build deltas.
+        ``ErrorBudget`` is the only source of build deltas.  ``interpret``
+        (Pallas interpret mode) defaults to the platform's answer —
+        interpret on CPU hosts, compiled kernels on the chip — and the
+        resolved value is what every table of the session runs with.
         """
+        interpret = resolve_interpret(interpret)
         missing = set(datasets) ^ set(specs)
         if missing:
             raise ValueError(f"datasets and specs disagree on tables: "
                              f"{sorted(missing)}")
-        tables = {}
+        jobs = {}
         for name, spec in specs.items():
             data = datasets[name]
             if spec.agg == "count2d":
@@ -258,9 +277,29 @@ class PolyFit:
             elif not (isinstance(data, tuple) and len(data) == 2):
                 raise ValueError(f"table {name!r}: {spec.agg} data must be "
                                  "(keys, measures)")
-            tables[name] = _Table(name, spec, data, backend=backend,
-                                  interpret=interpret, bq=bq,
-                                  min_bucket=min_bucket)
+            jobs[name] = (spec, data)
+
+        # construction is f64 host work wherever the session runs: each
+        # table builds with the host CPU as JAX's default device, then its
+        # finished serving state is committed to the serving device once
+        host = jax.local_devices(backend="cpu")[0]
+        serving = jax.devices()[0]
+
+        def build(name: str) -> _Table:
+            t0 = time.perf_counter()
+            with jax.default_device(host):   # thread-local: set per build
+                table = _Table(name, *jobs[name], backend=backend,
+                               interpret=interpret, bq=bq,
+                               min_bucket=min_bucket)
+            table.place(serving)
+            table.build_seconds = time.perf_counter() - t0
+            return table
+
+        # tables are independent, and their host fitting (HiGHS LPs, numpy
+        # sorts) releases the GIL: building them side by side cuts the
+        # set-up wall time at deployment sizes
+        with ThreadPoolExecutor(max_workers=max(1, len(jobs))) as pool:
+            tables = dict(zip(jobs, pool.map(build, jobs)))
         return cls(tables, backend=backend, interpret=interpret, bq=bq,
                    min_bucket=min_bucket)
 
@@ -282,6 +321,11 @@ class PolyFit:
 
     def size_bytes(self) -> Dict[str, int]:
         return {k: t.size_bytes() for k, t in self._tables.items()}
+
+    def build_seconds(self) -> Dict[str, float]:
+        """Wall seconds each table took to fit (tables fit concurrently,
+        so these overlap; their max bounds the session's set-up time)."""
+        return {k: t.build_seconds for k, t in self._tables.items()}
 
     def _table(self, name: str) -> _Table:
         t = self._tables.get(name)

@@ -46,6 +46,7 @@ Pipeline (COUNT is the aggregate the paper evaluates; SUM/MAX/MIN over
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -280,59 +281,61 @@ class MergeSortTree:
         """Dominance max of measures (-inf if the dominated set is empty)."""
         return mst_dommax(self.xs, self.ys_levels, self.wpmax_levels, u, v)
 
+    @functools.cached_property
+    def _block_keys(self) -> Tuple[np.ndarray, np.ndarray]:
+        """Host search structure for the construction-time oracles:
+        ``(ys_sorted, keys)`` with ``keys[l, p] = (p >> l) * (n + 1) +
+        rank(ys_levels[l, p])``, where ``rank(y)`` counts the ys <= y.
+        Ranks preserve ``<=`` and the block index dominates, so each level
+        is one globally sorted int64 array: an in-block count of ``y <= v``
+        is a single ``searchsorted`` instead of an unrolled binary search."""
+        ysl = np.asarray(self.ys_levels)
+        levels, n = ysl.shape
+        ys_sorted = ysl[levels - 1]      # top level: one block, fully sorted
+        rank = np.searchsorted(ys_sorted, ysl, side="right")
+        block = np.arange(n)[None, :] >> np.arange(levels)[:, None]
+        return ys_sorted, block * (n + 1) + rank
+
+    def _block_counts_np(self, i: np.ndarray, v: np.ndarray):
+        """Per level, top-down: ``(take, pos, lo)`` of the BIT walk that
+        ``mst_count_prefix`` unrolls — ``lo`` is the in-block count of
+        ``y <= v`` (meaningful where ``take``), bit-identical to the
+        device twin's binary search."""
+        ys_sorted, keys = self._block_keys
+        n = keys.shape[1]
+        rv = np.searchsorted(ys_sorted, v, side="right")
+        pos = np.zeros_like(i)
+        for l in range(keys.shape[0] - 1, -1, -1):
+            b = 1 << l
+            take = pos + b <= i
+            lo = np.searchsorted(keys[l], (pos >> l) * (n + 1) + rv,
+                                 side="right") - pos
+            yield l, take, pos, lo
+            pos = np.where(take, pos + b, pos)
+
     def cf_np(self, u, v) -> np.ndarray:
         """CF_count on the host (numpy) — used during construction where
         region shapes vary per call and JAX would recompile every time."""
-        xs = np.asarray(self.xs)
-        ysl = np.asarray(self.ys_levels)
-        n = len(xs)
-        i = np.searchsorted(xs, np.asarray(u, np.float64), side="right")
-        v = np.asarray(v, np.float64)
+        i = np.searchsorted(np.asarray(self.xs), np.asarray(u, np.float64),
+                            side="right")
         total = np.zeros_like(i)
-        pos = np.zeros_like(i)
-        for l in range(ysl.shape[0] - 1, -1, -1):
-            b = 1 << l
-            take = pos + b <= i
-            lo = np.zeros_like(i)
-            hi = np.full_like(i, b)
-            for _ in range(l + 1):
-                active = lo < hi
-                mid = (lo + hi) // 2
-                idx = np.clip(pos + np.minimum(mid, b - 1), 0, n - 1)
-                go_right = active & (ysl[l][idx] <= v)
-                lo = np.where(go_right, mid + 1, lo)
-                hi = np.where(active & ~go_right, mid, hi)
+        for _, take, _, lo in self._block_counts_np(
+                i, np.asarray(v, np.float64)):
             total = total + np.where(take, lo, 0)
-            pos = np.where(take, pos + b, pos)
         return total
 
     def _weighted_prefix_np(self, i: np.ndarray, v: np.ndarray,
                             mode: str) -> np.ndarray:
         """Host twin of ``mst_weighted_prefix`` (construction-time oracle)."""
         is_sum = mode == "sum"
-        xs = np.asarray(self.xs)
-        ysl = np.asarray(self.ys_levels)
         wacc = np.asarray(self.wcum_levels if is_sum else self.wpmax_levels)
-        n = len(xs)
+        n = wacc.shape[1]
         ident = 0.0 if is_sum else -np.inf
         total = np.full(np.shape(i), ident)
-        pos = np.zeros_like(i)
-        for l in range(ysl.shape[0] - 1, -1, -1):
-            b = 1 << l
-            take = pos + b <= i
-            lo = np.zeros_like(i)
-            hi = np.full_like(i, b)
-            for _ in range(l + 1):
-                active = lo < hi
-                mid = (lo + hi) // 2
-                idx = np.clip(pos + np.minimum(mid, b - 1), 0, n - 1)
-                go_right = active & (ysl[l][idx] <= v)
-                lo = np.where(go_right, mid + 1, lo)
-                hi = np.where(active & ~go_right, mid, hi)
+        for l, take, pos, lo in self._block_counts_np(i, v):
             val = wacc[l][np.clip(pos + lo - 1, 0, n - 1)]
             val = np.where(take & (lo > 0), val, ident)
             total = total + val if is_sum else np.maximum(total, val)
-            pos = np.where(take, pos + b, pos)
         return total
 
     def cf_sum_np(self, u, v) -> np.ndarray:

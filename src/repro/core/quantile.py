@@ -76,9 +76,11 @@ def boundary_array(coeffs: jnp.ndarray) -> jnp.ndarray:
     """``B[i] = max_{j<=i} P_j(+1)`` — running max of segment endpoint CF
     values.  Sorted by construction; zero-coefficient padding rows evaluate
     to 0 and sit at the tail, where the running max has already saturated.
+    A log-depth scan: ``lax.cummax`` lowers through ``reduce_window``,
+    slow to compile in f64 for a TPU.
     """
-    return jax.lax.cummax(horner(coeffs, jnp.ones(coeffs.shape[0],
-                                                  coeffs.dtype)))
+    return jax.lax.associative_scan(
+        jnp.maximum, horner(coeffs, jnp.ones(coeffs.shape[0], coeffs.dtype)))
 
 
 def _newton_root(c: jnp.ndarray, t: jnp.ndarray) -> jnp.ndarray:

@@ -28,7 +28,7 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
 import numpy as np  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
 
 jax.config.update("jax_enable_x64", True)
@@ -91,7 +91,7 @@ def check_int8_ring_allreduce() -> None:
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.normal(0, 1, (WORLD, WORLD * chunk)), jnp.float32)
     got = jax.jit(shard_map(body, mesh=mesh, in_specs=P("ring"),
-                            out_specs=P("ring"), check_rep=False))(x)
+                            out_specs=P("ring"), check_vma=False))(x)
     got = np.asarray(got).reshape(WORLD, WORLD, chunk)  # per-device copies
     exact = np.asarray(x).reshape(WORLD, WORLD, chunk).sum(0)
     # each chunk crosses <= W-1 quantized hops, each adding <= scale/2
@@ -135,7 +135,7 @@ def check_pipeline_parallelism() -> None:
         return jax.lax.psum(outs, "pp")       # only the last stage wrote
 
     got = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("pp"), P()),
-                            out_specs=P(), check_rep=False))(ws, xs)
+                            out_specs=P(), check_vma=False))(ws, xs)
     ref = xs
     for s in range(WORLD):
         ref = jax.vmap(lambda h, w=ws[s]: stage(w, h))(ref)

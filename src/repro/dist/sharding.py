@@ -18,9 +18,7 @@ to replication and the same launcher code runs on one CPU device, the
 (m/v shard exactly like their parameters, the step count replicates);
 ``batch_specs``/``cache_specs`` shard the batch dimension over the
 data-parallel axes; ``named`` maps a spec pytree to ``NamedSharding``s
-for jit in/out_shardings; ``mesh_context`` papers over the moving
-``set_mesh``/``use_mesh`` API (falling back to the ``Mesh`` context
-manager itself on older jax).
+for jit in/out_shardings.
 """
 from __future__ import annotations
 
@@ -32,7 +30,7 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 __all__ = ["param_specs", "state_specs", "batch_specs", "cache_specs",
-           "named", "mesh_context"]
+           "named"]
 
 # leaves reached through these keys are scan-stacked with a leading layer
 # axis that must stay replicated
@@ -155,14 +153,3 @@ def named(mesh, specs: Any) -> Any:
     (jit in/out_shardings take sharding pytrees, not spec pytrees)."""
     return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
                         is_leaf=lambda x: isinstance(x, P))
-
-
-def mesh_context(mesh):
-    """A context manager making ``mesh`` ambient, across jax versions:
-    ``jax.sharding.set_mesh`` / ``use_mesh`` where they exist, else the
-    ``Mesh`` object itself (the legacy context-manager protocol)."""
-    for name in ("set_mesh", "use_mesh"):
-        fn = getattr(jax.sharding, name, None)
-        if fn is not None:
-            return fn(mesh)
-    return mesh

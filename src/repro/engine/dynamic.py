@@ -70,7 +70,7 @@ from ..kernels.delta_scan import (delta_count2d_gather_pallas,
                                   delta_sum_gather_pallas, delta_sum_pallas)
 from ..core.poly import horner
 from ..core.quantile import boundary_array, invert_cf, rank_slack
-from ..kernels.poly_eval import DEFAULT_BQ
+from ..kernels.poly_eval import DEFAULT_BQ, resolve_interpret
 from .engine import (QuantileResult, _bucket_size, _pad_bucket, check_pow2,
                      raw_count2d, raw_eval2d, raw_extremum, raw_sum,
                      truth_count2d, truth_dommax2d, truth_extremum,
@@ -216,9 +216,17 @@ def _merge_sorted(cap: int, keys, vals, new_k, new_v):
     return k[order][:cap], v[order][:cap]
 
 
+def _scan_sum(x, axis: int = 0):
+    """Inclusive prefix sum as a log-depth scan.  XLA lowers ``cumsum`` on
+    TPU through ``reduce_window``, which in f64 takes minutes to compile
+    for a v5e (about 160 s for 1024 values); the scan compiles in under a
+    second, and every platform adds in the same order."""
+    return jax.lax.associative_scan(jnp.add, x, axis=axis)
+
+
 def _prefix_sum_jnp(vals):
     """Exclusive prefix-sum array ((cap+1,)) over the sorted log's values."""
-    return jnp.concatenate([jnp.zeros((1,), vals.dtype), jnp.cumsum(vals)])
+    return jnp.concatenate([jnp.zeros((1,), vals.dtype), _scan_sum(vals)])
 
 
 def _sparse_table_jnp(vals, *, cap: int):
@@ -259,8 +267,9 @@ def _mst_levels_w_jnp(ys, ws, *, cap: int):
         w2 = jnp.take_along_axis(w.reshape(cap // b, b), perm, axis=1)
         y, w = y2.reshape(-1), w2.reshape(-1)
         ylv.append(y)
-        wcum.append(jnp.cumsum(w2, axis=1).reshape(-1))
-        wpmax.append(jax.lax.cummax(w2, axis=1).reshape(-1))
+        wcum.append(_scan_sum(w2, axis=1).reshape(-1))
+        wpmax.append(jax.lax.associative_scan(jnp.maximum, w2,
+                                              axis=1).reshape(-1))
     return jnp.stack(ylv), jnp.stack(wcum), jnp.stack(wpmax)
 
 
@@ -389,7 +398,8 @@ def _delta_dommax2d(u, v, kx, ky, wv, ylv, wpmax, *, backend, interpret, bq):
 
 @partial(jax.jit, static_argnames=("backend", "eps_rel", "interpret", "bq"))
 def _exec_dyn_sum(plan: IndexPlan, buf: DeltaBuffer, lq, uq, *, backend: str,
-                  eps_rel: Optional[float], interpret: bool, bq: int):
+                  eps_rel: Optional[float],
+                  interpret: Optional[bool], bq: int):
     dt = plan.dtype
     lqr, uqr = lq.astype(dt), uq.astype(dt)
     lqc = jnp.maximum(lqr, plan.domain_lo)
@@ -416,7 +426,7 @@ def _exec_dyn_sum(plan: IndexPlan, buf: DeltaBuffer, lq, uq, *, backend: str,
 
 @partial(jax.jit, static_argnames=("backend", "interpret", "bq"))
 def _exec_dyn_quantile(plan: IndexPlan, buf: DeltaBuffer, q, *, backend: str,
-                       interpret: bool, bq: int):
+                       interpret: Optional[bool], bq: int):
     """Certified quantile over the *updated* CF G = F + (ins - del).
 
     G is the CF of the live multiset (deletes remove existing rows), hence
@@ -519,7 +529,7 @@ def _exec_dyn_quantile(plan: IndexPlan, buf: DeltaBuffer, q, *, backend: str,
 @partial(jax.jit, static_argnames=("backend", "eps_rel", "interpret", "bq"))
 def _exec_dyn_extremum(plan: IndexPlan, buf: DeltaBuffer, lq, uq, *,
                        backend: str, eps_rel: Optional[float],
-                       interpret: bool, bq: int):
+                       interpret: Optional[bool], bq: int):
     """MAX space throughout; the delete log is empty by construction
     (extremal deletes shadow a victim — ``buf.vic_keys``/``buf.live_st`` —
     instead of populating the device delete log; see DeltaBuffer)."""
@@ -569,7 +579,7 @@ def _exec_dyn_extremum(plan: IndexPlan, buf: DeltaBuffer, lq, uq, *,
 @partial(jax.jit, static_argnames=("backend", "eps_rel", "interpret", "bq"))
 def _exec_dyn_count2d(plan: IndexPlan2D, buf: DeltaBuffer2D, lx, ux, ly, uy,
                       *, backend: str, eps_rel: Optional[float],
-                      interpret: bool, bq: int):
+                      interpret: Optional[bool], bq: int):
     dt = plan.dtype
     x0, x1, y0, y1 = plan.root
     lxr, uxr, lyr, uyr = (q.astype(dt) for q in (lx, ux, ly, uy))
@@ -594,7 +604,7 @@ def _exec_dyn_count2d(plan: IndexPlan2D, buf: DeltaBuffer2D, lx, ux, ly, uy,
 @partial(jax.jit, static_argnames=("backend", "eps_rel", "interpret", "bq"))
 def _exec_dyn_sum2d(plan: IndexPlan2D, buf: DeltaBuffer2D, lx, ux, ly, uy,
                     *, backend: str, eps_rel: Optional[float],
-                    interpret: bool, bq: int):
+                    interpret: Optional[bool], bq: int):
     dt = plan.dtype
     x0, x1, y0, y1 = plan.root
     lxr, uxr, lyr, uyr = (q.astype(dt) for q in (lx, ux, ly, uy))
@@ -621,7 +631,7 @@ def _exec_dyn_sum2d(plan: IndexPlan2D, buf: DeltaBuffer2D, lx, ux, ly, uy,
 @partial(jax.jit, static_argnames=("backend", "eps_rel", "interpret", "bq"))
 def _exec_dyn_dommax2d(plan: IndexPlan2D, buf: DeltaBuffer2D, u, v, *,
                        backend: str, eps_rel: Optional[float],
-                       interpret: bool, bq: int):
+                       interpret: Optional[bool], bq: int):
     """MAX space throughout; the delete log is empty by construction
     (extremal deletes shadow a victim — ``buf.vic_x``/``buf.vic_y``/
     ``buf.live_wpmax`` — instead of populating the device delete log)."""
@@ -671,7 +681,8 @@ def _exec_dyn_dommax2d(plan: IndexPlan2D, buf: DeltaBuffer2D, u, v, *,
 # ---------------------------------------------------------------------------
 
 def fused_executor(agg: str, dynamic: bool, *, backend: str,
-                   eps_rel: Optional[float], interpret: bool, bq: int,
+                   eps_rel: Optional[float],
+                   interpret: Optional[bool], bq: int,
                    deg: int):
     """A plain callable ``fn(plan, buf, *padded_ranges)`` with every static
     argument closed over — the unit the serving engine AOT-lowers
@@ -713,8 +724,8 @@ def fused_executor(agg: str, dynamic: bool, *, backend: str,
     return fn
 
 
-def fused_quantile_executor(dynamic: bool, *, backend: str, interpret: bool,
-                            bq: int, deg: int):
+def fused_quantile_executor(dynamic: bool, *, backend: str,
+                            interpret: Optional[bool], bq: int, deg: int):
     """The QUANTILE counterpart of ``fused_executor``: a plain callable
     ``fn(plan, buf, q)`` returning the certified (answer, lo, hi) triple
     over the padded fraction bucket.  Q_abs-only — there is no Q_rel
@@ -865,15 +876,16 @@ class _DeltaBufferedEngine:
 
     _refit_error: Optional[BaseException] = None
 
-    def _init_dynamic(self, *, backend: str, capacity: int, interpret: bool,
-                      bq: int, min_bucket: int, auto_refit: bool,
+    def _init_dynamic(self, *, backend: str, capacity: int,
+                      interpret: Optional[bool], bq: int,
+                      min_bucket: int, auto_refit: bool,
                       background: bool) -> None:
         check_pow2("capacity", capacity)
         check_pow2("bq", bq)
         check_pow2("min_bucket", min_bucket)
         self.backend = backend
         self.capacity = capacity
-        self.interpret = interpret
+        self.interpret = resolve_interpret(interpret)
         self.bq = bq
         self.min_bucket = min_bucket
         self.auto_refit = auto_refit
@@ -887,6 +899,13 @@ class _DeltaBufferedEngine:
         # a pending insert the snapshot already copied must be replayed at
         # install (the merge bakes the un-cancelled copy into the new base).
         self._merge_mark: Optional[Tuple[int, int]] = None
+
+    def place(self, device) -> None:
+        """Commit the serving state — plan and delta buffer — to ``device``
+        (the session builds on the host and places once, see
+        ``api/session.py``)."""
+        with self._lock:
+            self._state = jax.device_put(self._state, device)
 
     def add_install_listener(self, fn) -> None:
         """Register ``fn(preview)`` to run on the merge thread with the
@@ -994,7 +1013,7 @@ class DynamicEngine(_DeltaBufferedEngine):
     """
 
     def __init__(self, index: PolyFitIndex1D, *, backend: str = "xla",
-                 capacity: int = 1024, interpret: bool = True,
+                 capacity: int = 1024, interpret: Optional[bool] = None,
                  bq: int = DEFAULT_BQ, min_bucket: int = 64,
                  auto_refit: bool = True, background: bool = False,
                  drift_floor: float = 0.05):
@@ -1407,7 +1426,7 @@ class DynamicEngine2D(_DeltaBufferedEngine):
     last merge in ``last_refit_stats``)."""
 
     def __init__(self, index: PolyFitIndex2D, *, backend: str = "xla",
-                 capacity: int = 1024, interpret: bool = True,
+                 capacity: int = 1024, interpret: Optional[bool] = None,
                  bq: int = DEFAULT_BQ, min_bucket: int = 64,
                  auto_refit: bool = True, background: bool = False):
         if index.exact is None:

@@ -48,7 +48,7 @@ from ..kernels.leaf_eval2d import (corner_count2d_gather_pallas,
                                    corner_count2d_pallas,
                                    corner_eval2d_gather_pallas,
                                    corner_eval2d_pallas)
-from ..kernels.poly_eval import DEFAULT_BQ
+from ..kernels.poly_eval import DEFAULT_BQ, resolve_interpret
 from ..kernels.quantile_invert import quantile_invert_pallas
 from ..kernels.range_max import range_max_gather_pallas, range_max_pallas
 from ..kernels.range_sum import range_sum_gather_pallas, range_sum_pallas
@@ -120,8 +120,8 @@ def _cf_at(keys, cf, q):
 # both the static executors below and the dynamic ones in dynamic.py)
 # ---------------------------------------------------------------------------
 
-def raw_sum(plan: IndexPlan, lqc, uqc, *, backend: str, interpret: bool,
-            bq: int):
+def raw_sum(plan: IndexPlan, lqc, uqc, *, backend: str,
+            interpret: Optional[bool], bq: int):
     """Backend-dispatched raw SUM/COUNT approximation (clamped queries)."""
     if backend == "pallas":
         return range_sum_gather_pallas(lqc, uqc, plan.seg_lo, plan.seg_hi,
@@ -138,8 +138,8 @@ def raw_sum(plan: IndexPlan, lqc, uqc, *, backend: str, interpret: bool,
             - eval_segments(lqc, plan.seg_lo, plan.seg_hi, plan.coeffs))
 
 
-def raw_extremum(plan: IndexPlan, lqc, uqc, *, backend: str, interpret: bool,
-                 bq: int):
+def raw_extremum(plan: IndexPlan, lqc, uqc, *, backend: str,
+                 interpret: Optional[bool], bq: int):
     """Backend-dispatched raw MAX approximation, in MAX space (MIN plans run
     on negated measures end to end)."""
     if backend == "pallas":
@@ -158,7 +158,7 @@ def raw_extremum(plan: IndexPlan, lqc, uqc, *, backend: str, interpret: bool,
 
 
 def raw_count2d(plan: IndexPlan2D, lxc, uxc, lyc, uyc, *, backend: str,
-                interpret: bool, bq: int):
+                interpret: Optional[bool], bq: int):
     """Backend-dispatched raw 2-key COUNT approximation (clamped corners)."""
     if backend == "pallas" and plan.leaf_z is not None:
         return corner_count2d_gather_pallas(
@@ -181,8 +181,8 @@ def raw_count2d(plan: IndexPlan2D, lxc, uxc, lyc, uyc, *, backend: str,
     return ev(uxc, uyc) - ev(lxc, uyc) - ev(uxc, lyc) + ev(lxc, lyc)
 
 
-def raw_eval2d(plan: IndexPlan2D, uc, vc, *, backend: str, interpret: bool,
-               bq: int):
+def raw_eval2d(plan: IndexPlan2D, uc, vc, *, backend: str,
+               interpret: Optional[bool], bq: int):
     """Backend-dispatched single-corner evaluation P_{leaf(u,v)}(u, v) —
     the dominance MAX/MIN query path (clamped corners).  Dominance queries
     touch exactly one leaf, so there is no inclusion-exclusion step."""
@@ -247,7 +247,7 @@ def truth_dommax2d(plan: IndexPlan2D, u, v):
 
 @partial(jax.jit, static_argnames=("backend", "eps_rel", "interpret", "bq"))
 def _exec_sum(plan: IndexPlan, lq, uq, *, backend: str,
-              eps_rel: Optional[float], interpret: bool, bq: int):
+              eps_rel: Optional[float], interpret: Optional[bool], bq: int):
     dt = plan.dtype
     lqc = jnp.maximum(lq.astype(dt), plan.domain_lo)
     uqc = jnp.maximum(uq.astype(dt), plan.domain_lo)
@@ -265,7 +265,8 @@ def _exec_sum(plan: IndexPlan, lq, uq, *, backend: str,
 
 @partial(jax.jit, static_argnames=("backend", "eps_rel", "interpret", "bq"))
 def _exec_extremum(plan: IndexPlan, lq, uq, *, backend: str,
-                   eps_rel: Optional[float], interpret: bool, bq: int):
+                   eps_rel: Optional[float],
+                   interpret: Optional[bool], bq: int):
     dt = plan.dtype
     lqc = jnp.maximum(lq.astype(dt), plan.domain_lo)
     uqc = jnp.maximum(uq.astype(dt), plan.domain_lo)
@@ -287,7 +288,8 @@ def _exec_extremum(plan: IndexPlan, lq, uq, *, backend: str,
 
 @partial(jax.jit, static_argnames=("backend", "eps_rel", "interpret", "bq"))
 def _exec_rect2d(plan: IndexPlan2D, lx, ux, ly, uy, *, backend: str,
-                 eps_rel: Optional[float], interpret: bool, bq: int):
+                 eps_rel: Optional[float], interpret: Optional[bool],
+                 bq: int):
     """Shared 4-corner rectangle executor for 2-key COUNT and SUM (the raw
     path is identical — only the exact-refinement truth differs, selected
     at trace time from the plan's static ``agg``)."""
@@ -308,7 +310,8 @@ def _exec_rect2d(plan: IndexPlan2D, lx, ux, ly, uy, *, backend: str,
 
 @partial(jax.jit, static_argnames=("backend", "eps_rel", "interpret", "bq"))
 def _exec_extremum2d(plan: IndexPlan2D, u, v, *, backend: str,
-                     eps_rel: Optional[float], interpret: bool, bq: int):
+                     eps_rel: Optional[float],
+                     interpret: Optional[bool], bq: int):
     """Dominance MAX/MIN: one fitted-surface evaluation per corner, in MAX
     space throughout (min2d plans are built on negated measures)."""
     dt = plan.dtype
@@ -359,7 +362,8 @@ def _check_backend(backend: str):
 
 
 def execute_sum(plan: IndexPlan, lq, uq, *, backend: str = "xla",
-                eps_rel: Optional[float] = None, interpret: bool = True,
+                eps_rel: Optional[float] = None,
+                interpret: Optional[bool] = None,
                 bq: int = DEFAULT_BQ, min_bucket: int = 64) -> QueryResult:
     """1-D SUM/COUNT over (lq, uq] through the fused jitted executor."""
     assert plan.agg in ("sum", "count"), plan.agg
@@ -375,8 +379,8 @@ def execute_sum(plan: IndexPlan, lq, uq, *, backend: str = "xla",
 
 
 @partial(jax.jit, static_argnames=("backend", "interpret", "bq"))
-def _exec_quantile(plan: IndexPlan, q, *, backend: str, interpret: bool,
-                   bq: int):
+def _exec_quantile(plan: IndexPlan, q, *, backend: str,
+                   interpret: Optional[bool], bq: int):
     dt = plan.dtype
     qc = jnp.clip(q.astype(dt), 0.0, 1.0)
     err = (plan.seg_err if plan.seg_err is not None
@@ -413,7 +417,7 @@ def _exec_quantile(plan: IndexPlan, q, *, backend: str, interpret: bool,
 
 
 def execute_quantile(plan: IndexPlan, q, *, backend: str = "xla",
-                     interpret: bool = True, bq: int = DEFAULT_BQ,
+                     interpret: Optional[bool] = None, bq: int = DEFAULT_BQ,
                      min_bucket: int = 64) -> QuantileResult:
     """Certified 1-D QUANTILE by CF inversion (DESIGN.md §16).
 
@@ -437,7 +441,8 @@ def execute_quantile(plan: IndexPlan, q, *, backend: str = "xla",
 
 
 def execute_extremum(plan: IndexPlan, lq, uq, *, backend: str = "xla",
-                     eps_rel: Optional[float] = None, interpret: bool = True,
+                     eps_rel: Optional[float] = None,
+                     interpret: Optional[bool] = None,
                      bq: int = DEFAULT_BQ, min_bucket: int = 64) -> QueryResult:
     """1-D MAX/MIN over [lq, uq] (MIN plans run on negated measures)."""
     assert plan.agg in ("max", "min"), plan.agg
@@ -474,7 +479,7 @@ def _execute_rect2d(plan: IndexPlan2D, lx, ux, ly, uy, *, backend, eps_rel,
 
 def execute_count2d(plan: IndexPlan2D, lx, ux, ly, uy, *,
                     backend: str = "xla", eps_rel: Optional[float] = None,
-                    interpret: bool = True, bq: int = DEFAULT_BQ,
+                    interpret: Optional[bool] = None, bq: int = DEFAULT_BQ,
                     min_bucket: int = 64) -> QueryResult:
     """2-key COUNT over (lx, ux] x (ly, uy] via 4-corner inclusion-exclusion."""
     assert plan.agg == "count2d", plan.agg
@@ -485,7 +490,7 @@ def execute_count2d(plan: IndexPlan2D, lx, ux, ly, uy, *,
 
 def execute_sum2d(plan: IndexPlan2D, lx, ux, ly, uy, *,
                   backend: str = "xla", eps_rel: Optional[float] = None,
-                  interpret: bool = True, bq: int = DEFAULT_BQ,
+                  interpret: Optional[bool] = None, bq: int = DEFAULT_BQ,
                   min_bucket: int = 64) -> QueryResult:
     """2-key SUM over (lx, ux] x (ly, uy]: the same 4-corner path over a
     CF_sum-fitted plan, |A - R| <= 4*delta (DESIGN.md §12)."""
@@ -497,7 +502,7 @@ def execute_sum2d(plan: IndexPlan2D, lx, ux, ly, uy, *,
 
 def execute_extremum2d(plan: IndexPlan2D, u, v, *, backend: str = "xla",
                        eps_rel: Optional[float] = None,
-                       interpret: bool = True, bq: int = DEFAULT_BQ,
+                       interpret: Optional[bool] = None, bq: int = DEFAULT_BQ,
                        min_bucket: int = 64) -> QueryResult:
     """Dominance MAX/MIN at (u, v): the extremal measure over
     {x <= u, y <= v}, |A - R| <= delta (min2d plans run on negated
@@ -516,7 +521,7 @@ def execute_extremum2d(plan: IndexPlan2D, u, v, *, backend: str = "xla",
 
 def execute(plan: Union[IndexPlan, IndexPlan2D], ranges, *,
             backend: str = "xla", eps_rel: Optional[float] = None,
-            interpret: bool = True, bq: int = DEFAULT_BQ,
+            interpret: Optional[bool] = None, bq: int = DEFAULT_BQ,
             min_bucket: int = 64) -> QueryResult:
     """Dispatch on the plan: (lq, uq) for 1-D, (lx, ux, ly, uy) for 2-D
     rectangles, (u, v) for 2-D dominance MAX/MIN."""
@@ -546,7 +551,8 @@ class Engine:
 
     One instance serves any number of plans; jit compiles (and caches) one
     executable per (aggregate, backend, batch-bucket, plan-layout).
-    ``interpret`` controls Pallas interpret mode (True for CPU hosts).
+    ``interpret`` controls Pallas interpret mode (default: from the
+    platform, ``kernels.poly_eval.resolve_interpret``).
 
     Every method is a shim binding this instance's (backend, interpret, bq,
     min_bucket) onto the module-level ``execute_*`` dispatch functions — the
@@ -554,13 +560,13 @@ class Engine:
     hit bit-identical executors.
     """
 
-    def __init__(self, backend: str = "xla", interpret: bool = True,
+    def __init__(self, backend: str = "xla", interpret: Optional[bool] = None,
                  bq: int = DEFAULT_BQ, min_bucket: int = 64):
         _check_backend(backend)
         check_pow2("bq", bq)
         check_pow2("min_bucket", min_bucket)
         self.backend = backend
-        self.interpret = interpret
+        self.interpret = resolve_interpret(interpret)
         self.bq = bq
         self.min_bucket = min_bucket
 

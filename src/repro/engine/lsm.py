@@ -339,7 +339,8 @@ _LEVEL_CORES = {
 }
 
 
-def level_executor(agg: str, *, backend: str, interpret: bool, bq: int,
+def level_executor(agg: str, *, backend: str,
+                   interpret: Optional[bool], bq: int,
                    with_truth: bool):
     """A plain callable ``fn(level, *padded_queries)`` with all statics
     closed over — the per-level unit the serving engine AOT-lowers and
@@ -355,7 +356,8 @@ def level_executor(agg: str, *, backend: str, interpret: bool, bq: int,
 
 @partial(jax.jit,
          static_argnames=("agg", "backend", "interpret", "bq", "with_truth"))
-def _run_level(lvl, *qs, agg: str, backend: str, interpret: bool, bq: int,
+def _run_level(lvl, *qs, agg: str, backend: str,
+               interpret: Optional[bool], bq: int,
                with_truth: bool):
     return _LEVEL_CORES[agg](lvl, *qs, backend=backend, interpret=interpret,
                              bq=bq, with_truth=with_truth)
@@ -400,7 +402,8 @@ def _buf_corr_extremal(buf, qs, *, agg, backend, interpret, bq):
 @partial(jax.jit, static_argnames=("agg", "backend", "eps_rel", "interpret",
                                    "bq", "bound", "has_buf"))
 def _combine_additive(parts, truths, buf, qs, *, agg: str, backend: str,
-                      eps_rel, interpret: bool, bq: int, bound: float,
+                      eps_rel,
+                      interpret: Optional[bool], bq: int, bound: float,
                       has_buf: bool):
     """SUM/COUNT/rect2d fusion: per-level partials add; the composed bound
     drives the same acceptance shape the flat executors use (identical
@@ -434,7 +437,8 @@ def _combine_additive(parts, truths, buf, qs, *, agg: str, backend: str,
 @partial(jax.jit, static_argnames=("agg", "backend", "eps_rel", "interpret",
                                    "bq", "bound", "has_buf"))
 def _combine_extremal(parts, exacts, threats, buf, qs, *, agg: str,
-                      backend: str, eps_rel, interpret: bool, bq: int,
+                      backend: str, eps_rel,
+                      interpret: Optional[bool], bq: int,
                       bound: float, has_buf: bool):
     """MAX/MIN fusion (MAX space in, answer space out): partials max
     across levels; any threatened level (range covers a victim) forces
@@ -467,7 +471,7 @@ def _combine_extremal(parts, exacts, threats, buf, qs, *, agg: str,
 
 
 def combine_levels(agg: str, level_outs, buf, qs, *, backend: str,
-                   eps_rel, interpret: bool, bq: int, bound: float):
+                   eps_rel, interpret: Optional[bool], bq: int, bound: float):
     """Fuse per-level core outputs (+ optional delta buffer) into the
     final (ans, approx, refined) triple."""
     has_buf = buf is not None
@@ -493,7 +497,7 @@ def combine_levels(agg: str, level_outs, buf, qs, *, backend: str,
 # ---------------------------------------------------------------------------
 
 def execute_lsm(lsm, buf, ranges, *, backend: str = "xla", eps_rel=None,
-                interpret: bool = True, bq: int = DEFAULT_BQ,
+                interpret: Optional[bool] = None, bq: int = DEFAULT_BQ,
                 min_bucket: int = 64, level_runner=None) -> QueryResult:
     """Execute a query batch against an ``LsmPlan``/``LsmPlan2D`` ladder
     plus an optional level-0 delta buffer.
@@ -656,7 +660,8 @@ class _LsmBase(_DeltaBufferedEngine):
     """
 
     def _init_lsm(self, *, agg: str, backend: str, capacity: int,
-                  growth: int, interpret: bool, bq: int, min_bucket: int,
+                  growth: int,
+                  interpret: Optional[bool], bq: int, min_bucket: int,
                   auto_refit: bool, background: bool, policy, dim: int) -> None:
         if growth < 2:
             raise ValueError(f"growth must be >= 2, got {growth}")
@@ -693,6 +698,16 @@ class _LsmBase(_DeltaBufferedEngine):
         return self._state[0]
 
     lsm_plan = plan
+
+    def place(self, device) -> None:
+        """Commit every level and the buffer to ``device``: level refreshes
+        reuse ``h.level.plan``, so placing the levels keeps later ladders
+        on the device too."""
+        with self._lock:
+            for h in self._levels.values():
+                h.level = jax.device_put(h.level, device)
+            self._state = (self._ladder(),
+                           jax.device_put(self._state[1], device))
 
     @property
     def n_levels(self) -> int:
@@ -966,7 +981,7 @@ class LsmEngine(_LsmBase):
     def __init__(self, keys, measures=None, *, agg: str = "sum",
                  deg: int = 2, delta: float = 100.0, backend: str = "xla",
                  capacity: int = 1024, growth: int = 4,
-                 interpret: bool = True, bq: int = DEFAULT_BQ,
+                 interpret: Optional[bool] = None, bq: int = DEFAULT_BQ,
                  min_bucket: int = 64, auto_refit: bool = True,
                  background: bool = False, policy=None):
         if agg not in ("sum", "count", "max", "min"):
@@ -1146,7 +1161,7 @@ class LsmEngine2D(_LsmBase):
                  deg: int = 3, delta: float = 100.0, grid: int = 8,
                  max_depth: int = 12, backend: str = "xla",
                  capacity: int = 1024, growth: int = 4,
-                 interpret: bool = True, bq: int = DEFAULT_BQ,
+                 interpret: Optional[bool] = None, bq: int = DEFAULT_BQ,
                  min_bucket: int = 64, auto_refit: bool = True,
                  background: bool = False, policy=None):
         if agg not in ("count2d", "sum2d", "max2d", "min2d"):

@@ -19,12 +19,13 @@ the part of the answer its key range owns:
 * **MAX/MIN** — Eq. 17 decomposes exactly: the boundary-segment closed-form
   extrema are computed by the shards owning ``lq``/``uq`` (same arithmetic
   as ``core.queries.max_eval_segments``), interior segments reduce through
-  per-shard sparse tables, and ``pmax`` combines — floating-point ``max``
-  is associative, so this too is bit-identical to the XLA backend.
+  per-shard sparse tables, and a cross-shard max combines (``_pmax``) —
+  floating-point ``max`` is associative, so this too is bit-identical to
+  the XLA backend.
 * **Exact refinement / delta buffers** — the refinement CF arrays and the
   ``DeltaBuffer`` logs are partitioned by the same key ranges.  Prefix-CF
   lookups use *global* prefix values stored at local positions (owner-masked
-  psum again), masked buffer maxima ride ``pmax``, so Q_rel refinement and
+  psum again), masked buffer maxima ride ``_pmax``, so Q_rel refinement and
   post-insert/delete dynamic answers stay bit-identical as well.
 
 The mapped body runs the XLA primitive path (``eval_segments`` /
@@ -45,8 +46,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.exact import build_sparse_table, sparse_table_range_max
 from ..core.index2d import mst_count_prefix, mst_weighted_prefix
@@ -55,7 +56,7 @@ from ..core.queries import QueryResult, poly_max_on_interval
 from ..kernels import ref as _ref
 from ..kernels.leaf_eval2d import _bivariate_horner
 from ..kernels.locate import INT_SENTINEL, bsearch_count, interleave2
-from ..kernels.poly_eval import DEFAULT_BQ
+from ..kernels.poly_eval import DEFAULT_BQ, resolve_interpret
 from .dynamic import (DeltaBuffer, DeltaBuffer2D, _exec_dyn_count2d,
                       _exec_dyn_dommax2d, _exec_dyn_sum2d)
 from .engine import (_bucket_size, _exec_extremum2d, _exec_rect2d,
@@ -69,6 +70,16 @@ __all__ = ["ShardedPlan", "ShardedDelta", "ShardedEngine", "shard_plan",
            "execute_lsm_sharded"]
 
 _AXIS = "shards"
+
+
+def _on_mesh(tree, mesh: Mesh, specs):
+    """Commit a partitioned pytree to ``mesh`` under the executors' own
+    in_specs (one spec for the whole tree, or a spec pytree): each shard's
+    slice lives on its own device and replicated arrays on every device,
+    so a dispatch moves no plan bytes between devices."""
+    shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                             is_leaf=lambda x: isinstance(x, P))
+    return jax.device_put(tree, shardings)
 
 
 def make_shard_mesh(nshards: int) -> Mesh:
@@ -295,6 +306,18 @@ def _psum_owned(val, own, zero=0.0):
     return jax.lax.psum(jnp.where(own, val, zero), _AXIS)
 
 
+def _pmax(val):
+    """Max across shards, exactly.  The TPU compiler lowers only summing
+    f64 all-reduces, so each shard writes its maxima into its own row of a
+    zero block, a psum gathers the rows (``x + 0 == x``), and every device
+    reduces the same values ``lax.pmax`` would."""
+    n = jax.lax.axis_size(_AXIS)
+    mine = (jnp.arange(n) == jax.lax.axis_index(_AXIS)).reshape(
+        (n,) + (1,) * val.ndim)
+    rows = jnp.where(mine, val[None], jnp.zeros((), val.dtype))
+    return jnp.max(jax.lax.psum(rows, _AXIS), axis=0)
+
+
 def _sum_endpoints(sp: ShardedPlan, lqc, uqc):
     """(F(lq), F(uq)) totals — each endpoint evaluated by its owner only."""
     rlo, rhi = sp.rlo[0], sp.rhi[0]
@@ -337,7 +360,7 @@ def _extremum_raw(sp: ShardedPlan, lqc, uqc):
     b = jnp.clip(iu - off, 0, hloc)
     m_mid = sparse_table_range_max(sp.st[0], a, b)
     part = jnp.maximum(jnp.maximum(m_left, m_right), m_mid)
-    return jax.lax.pmax(part, _AXIS)
+    return _pmax(part)
 
 
 def _truth_sum_tot(sp: ShardedPlan, lq, uq):
@@ -356,7 +379,7 @@ def _truth_extremum_tot(sp: ShardedPlan, lq, uq):
     keys = sp.ref_keys[0]
     i = jnp.searchsorted(keys, lq, side="left")
     j = jnp.searchsorted(keys, uq, side="right")
-    return jax.lax.pmax(sparse_table_range_max(sp.ref_st[0], i, j), _AXIS)
+    return _pmax(sparse_table_range_max(sp.ref_st[0], i, j))
 
 
 def _delta_sum_tot(keys, pcf, lq, uq, rlo, rhi):
@@ -372,7 +395,7 @@ def _delta_max_tot(keys, vals, lq, uq):
     """Exact buffered MAX over [lq, uq] — per-shard masked max + pmax."""
     member = (lq[:, None] <= keys[None, :]) & (keys[None, :] <= uq[:, None])
     part = jnp.max(jnp.where(member, vals[None, :], -jnp.inf), axis=1)
-    return jax.lax.pmax(part, _AXIS)
+    return _pmax(part)
 
 
 # ---------------------------------------------------------------------------
@@ -494,12 +517,13 @@ class ShardedEngine:
     """
 
     def __init__(self, nshards: int, *, mesh: Optional[Mesh] = None,
-                 min_bucket: int = 64):
+                 min_bucket: int = 64, interpret: Optional[bool] = None):
         check_pow2("nshards", nshards)
         check_pow2("min_bucket", min_bucket)
         self.nshards = nshards
         self.mesh = mesh if mesh is not None else make_shard_mesh(nshards)
         self.min_bucket = min_bucket
+        self.interpret = resolve_interpret(interpret)
         self._plan_cache: dict = {}
         self._buf_cache: dict = {}
 
@@ -512,8 +536,9 @@ class ShardedEngine:
             return _lsm_cache_shard(self, plan, shard_lsm_plan)
         hit = self._plan_cache.get(id(plan))
         if hit is None or hit[0] is not plan:
-            self._plan_cache = {id(plan): (plan, shard_plan(plan,
-                                                           self.nshards))}
+            splan = _on_mesh(shard_plan(plan, self.nshards), self.mesh,
+                             P(_AXIS))
+            self._plan_cache = {id(plan): (plan, splan)}
             hit = self._plan_cache[id(plan)]
         return hit[1]
 
@@ -524,8 +549,8 @@ class ShardedEngine:
         # the plan's bounds
         hit = self._buf_cache.get(id(buf))
         if hit is None or hit[0] is not buf or hit[1] != splan.bounds:
-            self._buf_cache = {
-                id(buf): (buf, splan.bounds, shard_buffer(buf, splan))}
+            sbuf = _on_mesh(shard_buffer(buf, splan), self.mesh, P(_AXIS))
+            self._buf_cache = {id(buf): (buf, splan.bounds, sbuf)}
             hit = self._buf_cache[id(buf)]
         return hit[2]
 
@@ -593,7 +618,7 @@ class ShardedEngine:
         size = _bucket_size(n, self.min_bucket)
         qp = _pad_bucket(qs, size, jnp.asarray(0.5, qs.dtype))
         ans, lo, hi = _exec_dyn_quantile(plan, buf, qp, backend="xla",
-                                         interpret=True,
+                                         interpret=self.interpret,
                                          bq=min(DEFAULT_BQ, size))
         return QuantileResult(ans[:n], lo[:n], hi[:n])
 
@@ -610,7 +635,8 @@ class ShardedEngine:
         slsm = _lsm_cache_shard(self, lsm, shard_lsm_plan)
         return execute_lsm_sharded(slsm, buf, (lq, uq), mesh=self.mesh,
                                    eps_rel=eps_rel,
-                                   min_bucket=self.min_bucket)
+                                   min_bucket=self.min_bucket,
+                                   interpret=self.interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -923,12 +949,13 @@ class ShardedEngine2D:
     """
 
     def __init__(self, nshards: int, *, mesh: Optional[Mesh] = None,
-                 min_bucket: int = 64):
+                 min_bucket: int = 64, interpret: Optional[bool] = None):
         check_pow2("nshards", nshards)
         check_pow2("min_bucket", min_bucket)
         self.nshards = nshards
         self.mesh = mesh if mesh is not None else make_shard_mesh(nshards)
         self.min_bucket = min_bucket
+        self.interpret = resolve_interpret(interpret)
         self._plan_cache: dict = {}
 
     def shard(self, plan) -> ShardedPlan2D:
@@ -938,8 +965,9 @@ class ShardedEngine2D:
             return _lsm_cache_shard(self, plan, shard_lsm_plan_2d)
         hit = self._plan_cache.get(id(plan))
         if hit is None or hit[0] is not plan:
-            self._plan_cache = {
-                id(plan): (plan, shard_plan_2d(plan, self.nshards))}
+            sp = shard_plan_2d(plan, self.nshards)
+            sp = _on_mesh(sp, self.mesh, _plan2d_inspec(sp))
+            self._plan_cache = {id(plan): (plan, sp)}
             hit = self._plan_cache[id(plan)]
         return hit[1]
 
@@ -973,12 +1001,14 @@ class ShardedEngine2D:
             bq = min(64, args[0].shape[0])
             if buf is None:
                 out = _exec_rect2d(plan, *args, backend="xla",
-                                   eps_rel=eps_rel, interpret=True, bq=bq)
+                                   eps_rel=eps_rel, interpret=self.interpret,
+                                   bq=bq)
             else:
                 dyn_exec = (_exec_dyn_sum2d if sp.agg == "sum2d"
                             else _exec_dyn_count2d)
                 out = dyn_exec(plan, buf, *args, backend="xla",
-                               eps_rel=eps_rel, interpret=True, bq=bq)
+                               eps_rel=eps_rel, interpret=self.interpret,
+                               bq=bq)
         elif buf is None:
             out = _exec_shard_rect2d(sp, *args, mesh=self.mesh,
                                      eps_rel=eps_rel)
@@ -1011,12 +1041,12 @@ class ShardedEngine2D:
             bq = min(64, args[0].shape[0])
             if buf is None:
                 out = _exec_extremum2d(plan, *args, backend="xla",
-                                       eps_rel=eps_rel, interpret=True,
-                                       bq=bq)
+                                       eps_rel=eps_rel,
+                                       interpret=self.interpret, bq=bq)
             else:
                 out = _exec_dyn_dommax2d(plan, buf, *args, backend="xla",
-                                         eps_rel=eps_rel, interpret=True,
-                                         bq=bq)
+                                         eps_rel=eps_rel,
+                                         interpret=self.interpret, bq=bq)
         elif buf is None:
             out = _exec_shard_dommax2d(sp, *args, mesh=self.mesh,
                                        eps_rel=eps_rel)
@@ -1041,7 +1071,8 @@ class ShardedEngine2D:
         slsm = _lsm_cache_shard(self, lsm, shard_lsm_plan_2d)
         return execute_lsm_sharded(slsm, buf, ranges, mesh=self.mesh,
                                    eps_rel=eps_rel,
-                                   min_bucket=self.min_bucket)
+                                   min_bucket=self.min_bucket,
+                                   interpret=self.interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -1228,7 +1259,8 @@ _LSM_SHARD_CORES = {
 
 
 def execute_lsm_sharded(slsm, buf, ranges, *, mesh: Mesh, eps_rel=None,
-                        min_bucket: int = 64) -> QueryResult:
+                        min_bucket: int = 64,
+                        interpret: Optional[bool] = None) -> QueryResult:
     """Fuse a query batch across a sharded level ladder (Q_abs only).
 
     Per-level raw evaluations run sharded; the exact corrections and the
@@ -1258,6 +1290,6 @@ def execute_lsm_sharded(slsm, buf, ranges, *, mesh: Mesh, eps_rel=None,
             for lvl, sp in zip(slsm.levels, slsm.slevels)]
     bound = composed_bound(agg, slsm.deltas)
     ans, approx, refined = combine_levels(
-        agg, outs, buf, qs, backend="xla", eps_rel=None, interpret=True,
+        agg, outs, buf, qs, backend="xla", eps_rel=None, interpret=interpret,
         bq=min(64, size), bound=bound)
     return QueryResult(ans[:n], approx[:n], refined[:n])

@@ -22,12 +22,13 @@ import threading
 from collections import deque
 from typing import List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..core.index import build_index_1d
 from ..core.queries import QueryResult
-from ..kernels.poly_eval import DEFAULT_BQ
+from ..kernels.poly_eval import DEFAULT_BQ, resolve_interpret
 from .dynamic import DeltaBuffer, _append_1d, _pad_batch
 from .engine import check_pow2
 from .lsm import LsmLevel, LsmPlan, composed_bound, execute_lsm
@@ -48,7 +49,7 @@ class WindowEngine:
     def __init__(self, keys=None, measures=None, *, agg: str = "count",
                  delta: float = 64.0, deg: int = 2, ring: int = 8,
                  capacity: int = 1024, backend: str = "xla",
-                 interpret: bool = True, bq: int = DEFAULT_BQ,
+                 interpret: Optional[bool] = None, bq: int = DEFAULT_BQ,
                  min_bucket: int = 64):
         if agg not in ("sum", "count"):
             raise ValueError("windowed aggregates support 1-D SUM/COUNT "
@@ -64,7 +65,7 @@ class WindowEngine:
         self.ring = ring
         self.capacity = capacity
         self.backend = backend
-        self.interpret = interpret
+        self.interpret = resolve_interpret(interpret)
         self.bq = bq
         self.min_bucket = min_bucket
         self._lock = threading.RLock()
@@ -77,6 +78,14 @@ class WindowEngine:
             self._ring.append((0, self._build_level(
                 np.atleast_1d(np.asarray(keys, np.float64)), measures, 0)))
             self.epoch = 1
+
+    def place(self, device) -> None:
+        """Commit the sealed epochs and the open epoch's buffer to
+        ``device`` (the session builds on the host and places once)."""
+        with self._lock:
+            self._ring = deque(((eid, jax.device_put(lvl, device))
+                                for eid, lvl in self._ring), maxlen=self.ring)
+            self._buf = jax.device_put(self._buf, device)
 
     # -- epoch lifecycle -------------------------------------------------
 

@@ -38,6 +38,7 @@ maintain on append (engine/dynamic.py):
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -46,7 +47,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core.index2d import mst_count_prefix, mst_weighted_prefix
 from .locate import bsearch_count, rmq_gather
-from .poly_eval import DEFAULT_BH, DEFAULT_BQ
+from .poly_eval import DEFAULT_BH, DEFAULT_BQ, resolve_interpret
 
 __all__ = ["delta_sum_pallas", "delta_max_pallas", "delta_count2d_pallas",
            "delta_sum_gather_pallas", "delta_max_gather_pallas",
@@ -78,7 +79,7 @@ def _delta_sum_kernel(lq_ref, uq_ref, k_ref, v_ref, out_ref, acc,
 
 
 def delta_sum_pallas(lq, uq, keys, vals, bq: int = DEFAULT_BQ,
-                     bd: int = DEFAULT_BH, interpret: bool = True):
+                     bd: int = DEFAULT_BH, interpret: Optional[bool] = None):
     """Exact sum of buffered measures with key in (lq, uq] per query."""
     Q, D = lq.shape[0], keys.shape[0]
     bd = min(bd, D)
@@ -97,7 +98,7 @@ def delta_sum_pallas(lq, uq, keys, vals, bq: int = DEFAULT_BQ,
         out_specs=pl.BlockSpec((bq,), lambda i, j: (i,)),
         out_shape=jax.ShapeDtypeStruct((Q,), vals.dtype),
         scratch_shapes=[pltpu.VMEM((bq,), vals.dtype)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lq, uq, keys, vals)
 
 
@@ -111,7 +112,7 @@ def _delta_sum_gather_kernel(lq_ref, uq_ref, k_ref, cf_ref, out_ref):
 
 
 def delta_sum_gather_pallas(lq, uq, keys, cf, bq: int = DEFAULT_BQ,
-                            interpret: bool = True):
+                            interpret: Optional[bool] = None):
     """Exact sum of buffered measures with key in (lq, uq] via the buffer's
     exclusive prefix-sum array ``cf`` ((D+1,), cf[i] = sum(vals[:i]),
     maintained on append): two O(log D) binary searches + a subtraction."""
@@ -128,7 +129,7 @@ def delta_sum_gather_pallas(lq, uq, keys, cf, bq: int = DEFAULT_BQ,
         ],
         out_specs=pl.BlockSpec((bq,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((Q,), cf.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lq, uq, keys, cf)
 
 
@@ -154,7 +155,7 @@ def _delta_max_kernel(lq_ref, uq_ref, k_ref, v_ref, out_ref, acc,
 
 
 def delta_max_pallas(lq, uq, keys, vals, bq: int = DEFAULT_BQ,
-                     bd: int = DEFAULT_BH, interpret: bool = True):
+                     bd: int = DEFAULT_BH, interpret: Optional[bool] = None):
     """Exact max of buffered measures with key in [lq, uq] (-inf if none)."""
     Q, D = lq.shape[0], keys.shape[0]
     bd = min(bd, D)
@@ -173,7 +174,7 @@ def delta_max_pallas(lq, uq, keys, vals, bq: int = DEFAULT_BQ,
         out_specs=pl.BlockSpec((bq,), lambda i, j: (i,)),
         out_shape=jax.ShapeDtypeStruct((Q,), vals.dtype),
         scratch_shapes=[pltpu.VMEM((bq,), vals.dtype)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lq, uq, keys, vals)
 
 
@@ -186,7 +187,7 @@ def _delta_max_gather_kernel(lq_ref, uq_ref, k_ref, st_ref, out_ref):
 
 
 def delta_max_gather_pallas(lq, uq, keys, st, bq: int = DEFAULT_BQ,
-                            interpret: bool = True):
+                            interpret: Optional[bool] = None):
     """Exact max of buffered measures with key in [lq, uq] (-inf if none):
     locate the sorted log's covered span, then an O(1) two-gather RMQ
     against the buffer's sparse table (rebuilt on append)."""
@@ -204,7 +205,7 @@ def delta_max_gather_pallas(lq, uq, keys, st, bq: int = DEFAULT_BQ,
         ],
         out_specs=pl.BlockSpec((bq,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((Q,), st.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lq, uq, keys, st)
 
 
@@ -235,7 +236,7 @@ def _delta_count2d_kernel(lx_ref, ux_ref, ly_ref, uy_ref, kx_ref, ky_ref,
 
 def delta_count2d_pallas(lx, ux, ly, uy, keys_x, keys_y,
                          bq: int = DEFAULT_BQ, bd: int = DEFAULT_BH,
-                         interpret: bool = True, dtype=None):
+                         interpret: Optional[bool] = None, dtype=None):
     """Exact count of buffered points in (lx, ux] x (ly, uy] per query."""
     Q, D = lx.shape[0], keys_x.shape[0]
     bd = min(bd, D)
@@ -257,7 +258,7 @@ def delta_count2d_pallas(lx, ux, ly, uy, keys_x, keys_y,
         out_specs=pl.BlockSpec((bq,), lambda i, j: (i,)),
         out_shape=jax.ShapeDtypeStruct((Q,), dtype),
         scratch_shapes=[pltpu.VMEM((bq,), dtype)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lx, ux, ly, uy, keys_x, keys_y)
 
 
@@ -278,7 +279,8 @@ def _delta_count2d_gather_kernel(lx_ref, ux_ref, ly_ref, uy_ref,
 
 
 def delta_count2d_gather_pallas(lx, ux, ly, uy, keys_x, ys_levels,
-                                bq: int = DEFAULT_BQ, interpret: bool = True,
+                                bq: int = DEFAULT_BQ,
+                                interpret: Optional[bool] = None,
                                 dtype=None):
     """Exact count of buffered points in (lx, ux] x (ly, uy] per query in
     O(log^2 D): the buffer is x-sorted and ``ys_levels`` ((L, D), level l =
@@ -303,7 +305,7 @@ def delta_count2d_gather_pallas(lx, ux, ly, uy, keys_x, ys_levels,
         ],
         out_specs=pl.BlockSpec((bq,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((Q,), dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lx, ux, ly, uy, keys_x, ys_levels)
 
 
@@ -334,7 +336,7 @@ def _delta_sum2d_kernel(lx_ref, ux_ref, ly_ref, uy_ref, kx_ref, ky_ref,
 
 def delta_sum2d_pallas(lx, ux, ly, uy, keys_x, keys_y, wv,
                        bq: int = DEFAULT_BQ, bd: int = DEFAULT_BH,
-                       interpret: bool = True):
+                       interpret: Optional[bool] = None):
     """Exact sum of buffered measures over points in (lx, ux] x (ly, uy]
     per query (the weighted twin of ``delta_count2d_pallas``)."""
     Q, D = lx.shape[0], keys_x.shape[0]
@@ -357,7 +359,7 @@ def delta_sum2d_pallas(lx, ux, ly, uy, keys_x, keys_y, wv,
         out_specs=pl.BlockSpec((bq,), lambda i, j: (i,)),
         out_shape=jax.ShapeDtypeStruct((Q,), wv.dtype),
         scratch_shapes=[pltpu.VMEM((bq,), wv.dtype)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lx, ux, ly, uy, keys_x, keys_y, wv)
 
 
@@ -376,7 +378,8 @@ def _delta_sum2d_gather_kernel(lx_ref, ux_ref, ly_ref, uy_ref,
 
 
 def delta_sum2d_gather_pallas(lx, ux, ly, uy, keys_x, ys_levels, wcum_levels,
-                              bq: int = DEFAULT_BQ, interpret: bool = True):
+                              bq: int = DEFAULT_BQ,
+                              interpret: Optional[bool] = None):
     """Exact sum of buffered measures over (lx, ux] x (ly, uy] in
     O(log^2 D): the weighted merge-sort-tree correction — per-level
     block-sorted y arrays plus per-block inclusive weight prefix sums,
@@ -398,7 +401,7 @@ def delta_sum2d_gather_pallas(lx, ux, ly, uy, keys_x, ys_levels, wcum_levels,
         ],
         out_specs=pl.BlockSpec((bq,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((Q,), wcum_levels.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lx, ux, ly, uy, keys_x, ys_levels, wcum_levels)
 
 
@@ -425,7 +428,8 @@ def _delta_dommax2d_kernel(u_ref, v_ref, kx_ref, ky_ref, w_ref, out_ref,
 
 
 def delta_dommax2d_pallas(u, v, keys_x, keys_y, wv, bq: int = DEFAULT_BQ,
-                          bd: int = DEFAULT_BH, interpret: bool = True):
+                          bd: int = DEFAULT_BH,
+                          interpret: Optional[bool] = None):
     """Exact dominance max of buffered measures over {x <= u, y <= v} per
     query corner (-inf if none dominated)."""
     Q, D = u.shape[0], keys_x.shape[0]
@@ -446,7 +450,7 @@ def delta_dommax2d_pallas(u, v, keys_x, keys_y, wv, bq: int = DEFAULT_BQ,
         out_specs=pl.BlockSpec((bq,), lambda i, j: (i,)),
         out_shape=jax.ShapeDtypeStruct((Q,), wv.dtype),
         scratch_shapes=[pltpu.VMEM((bq,), wv.dtype)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(u, v, keys_x, keys_y, wv)
 
 
@@ -460,7 +464,7 @@ def _delta_dommax2d_gather_kernel(u_ref, v_ref, kx_ref, ylv_ref, wpmax_ref,
 
 def delta_dommax2d_gather_pallas(u, v, keys_x, ys_levels, wpmax_levels,
                                  bq: int = DEFAULT_BQ,
-                                 interpret: bool = True):
+                                 interpret: Optional[bool] = None):
     """Exact dominance max over {x <= u, y <= v} in O(log^2 D): the
     merge-sort-tree decomposition with per-block inclusive prefix *maxima*
     instead of prefix sums."""
@@ -479,5 +483,5 @@ def delta_dommax2d_gather_pallas(u, v, keys_x, ys_levels, wpmax_levels,
         ],
         out_specs=pl.BlockSpec((bq,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((Q,), wpmax_levels.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(u, v, keys_x, ys_levels, wpmax_levels)

@@ -32,6 +32,7 @@ membership scan — see kernels/locate.py and DESIGN.md §10.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -39,7 +40,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .locate import locate_leaf2d
-from .poly_eval import DEFAULT_BH, DEFAULT_BQ
+from .poly_eval import DEFAULT_BH, DEFAULT_BQ, resolve_interpret
 
 __all__ = ["corner_count2d_pallas", "corner_count2d_gather_pallas",
            "corner_eval2d_pallas", "corner_eval2d_gather_pallas"]
@@ -84,7 +85,8 @@ def _corner_count2d_gather_kernel(lx_ref, ux_ref, ly_ref, uy_ref,
 
 def corner_count2d_gather_pallas(lx, ux, ly, uy, xcuts, ycuts, leaf_z,
                                  bounds, coeffs, deg: int, depth: int,
-                                 bq: int = DEFAULT_BQ, interpret: bool = True):
+                                 bq: int = DEFAULT_BQ,
+                                 interpret: Optional[bool] = None):
     """Locate->gather 4-corner COUNT (DESIGN.md §10): the quadtree leaves
     are disjoint Morton intervals, so each corner resolves with three
     binary searches (cell x, cell y, leaf z) and one gathered bivariate
@@ -115,7 +117,7 @@ def corner_count2d_gather_pallas(lx, ux, ly, uy, xcuts, ycuts, leaf_z,
         ],
         out_specs=pl.BlockSpec((bq,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((Q,), coeffs.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lx, ux, ly, uy, xcuts, ycuts, leaf_z, bounds, coeffs)
 
 
@@ -133,7 +135,7 @@ def _corner_eval2d_gather_kernel(u_ref, v_ref, xcuts_ref, ycuts_ref, z_ref,
 
 def corner_eval2d_gather_pallas(u, v, xcuts, ycuts, leaf_z, bounds, coeffs,
                                 deg: int, depth: int, bq: int = DEFAULT_BQ,
-                                interpret: bool = True):
+                                interpret: Optional[bool] = None):
     """Single-corner leaf evaluation P_{leaf(u,v)}(u, v) via locate->gather
     (DESIGN.md §12): three binary searches resolve the corner's leaf in the
     z-sorted table, one gathered bivariate Horner evaluates it.  This is
@@ -161,7 +163,7 @@ def corner_eval2d_gather_pallas(u, v, xcuts, ycuts, leaf_z, bounds, coeffs,
         ],
         out_specs=pl.BlockSpec((bq,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((Q,), coeffs.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(u, v, xcuts, ycuts, leaf_z, bounds, coeffs)
 
 
@@ -192,7 +194,8 @@ def _corner_eval2d_kernel(u_ref, v_ref, mx0_ref, mx1_ref, my0_ref, my1_ref,
 
 def corner_eval2d_pallas(u, v, mx0, mx1, my0, my1, bounds, coeffs,
                          deg: int, bq: int = DEFAULT_BQ,
-                         bh: int = DEFAULT_BH, interpret: bool = True):
+                         bh: int = DEFAULT_BH,
+                         interpret: Optional[bool] = None):
     """Single-corner leaf evaluation over the flat leaf table — the one-hot
     membership twin of ``corner_eval2d_gather_pallas`` (the engine's
     ``pallas_scan`` backend and the deep-tree fallback).  Shapes pre-padded
@@ -220,7 +223,7 @@ def corner_eval2d_pallas(u, v, mx0, mx1, my0, my1, bounds, coeffs,
         out_specs=pl.BlockSpec((bq,), lambda i, j: (i,)),
         out_shape=jax.ShapeDtypeStruct((Q,), coeffs.dtype),
         scratch_shapes=[pltpu.VMEM((bq, k + 4), coeffs.dtype)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(u, v, mx0, mx1, my0, my1, bounds, coeffs)
 
 
@@ -277,7 +280,8 @@ def _corner_count2d_kernel(lx_ref, ux_ref, ly_ref, uy_ref,
 
 def corner_count2d_pallas(lx, ux, ly, uy, mx0, mx1, my0, my1, bounds, coeffs,
                           deg: int, bq: int = DEFAULT_BQ,
-                          bh: int = DEFAULT_BH, interpret: bool = True):
+                          bh: int = DEFAULT_BH,
+                          interpret: Optional[bool] = None):
     """4-corner COUNT over a flat leaf table; shapes pre-padded to block
     multiples and corners pre-clamped into the root region by the caller
     (the engine's count2d executor does both)."""
@@ -306,5 +310,5 @@ def corner_count2d_pallas(lx, ux, ly, uy, mx0, mx1, my0, my1, bounds, coeffs,
         out_specs=pl.BlockSpec((bq,), lambda i, j: (i,)),
         out_shape=jax.ShapeDtypeStruct((Q,), coeffs.dtype),
         scratch_shapes=[pltpu.VMEM((bq, 4 * (k + 4)), coeffs.dtype)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lx, ux, ly, uy, mx0, mx1, my0, my1, bounds, coeffs)

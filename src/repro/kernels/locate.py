@@ -34,12 +34,14 @@ exceeds every real key, so the counts never reach it.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from .poly_eval import DEFAULT_BQ
+from .poly_eval import DEFAULT_BQ, resolve_interpret
 
 __all__ = [
     "bsearch_count", "locate_segments", "floor_log2", "rmq_gather",
@@ -176,7 +178,8 @@ def _locate_kernel(q_ref, lo_ref, out_ref):
     out_ref[...] = locate_segments(lo_ref[...], q_ref[...])
 
 
-def locate_pallas(q, seg_lo, bq: int = DEFAULT_BQ, interpret: bool = True):
+def locate_pallas(q, seg_lo, bq: int = DEFAULT_BQ,
+                  interpret: Optional[bool] = None):
     """Segment id per query key: (Q,) int32 against sorted (Hp,) seg_lo.
 
     Grid over query blocks only — the boundary array is fully resident, and
@@ -193,5 +196,5 @@ def locate_pallas(q, seg_lo, bq: int = DEFAULT_BQ, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((bq,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((Q,), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, seg_lo)

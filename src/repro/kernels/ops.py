@@ -17,6 +17,7 @@ refinement — use ``repro.engine.Engine``.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -54,7 +55,7 @@ def _pad_queries(q, bq, fill):
 @functools.partial(jax.jit, static_argnames=("backend", "bq", "bh", "interpret"))
 def poly_eval(table: IndexPlan, q, backend: str = "pallas",
               bq: int = DEFAULT_BQ, bh: int = DEFAULT_BH,
-              interpret: bool = True):
+              interpret: Optional[bool] = None):
     q = jnp.asarray(q, table.coeffs.dtype)
     dom_lo = table.seg_lo[0]
     q = jnp.maximum(q, dom_lo)
@@ -72,7 +73,7 @@ def poly_eval(table: IndexPlan, q, backend: str = "pallas",
 @functools.partial(jax.jit, static_argnames=("backend", "bq", "bh", "interpret"))
 def range_sum(table: IndexPlan, lq, uq, backend: str = "pallas",
               bq: int = DEFAULT_BQ, bh: int = DEFAULT_BH,
-              interpret: bool = True):
+              interpret: Optional[bool] = None):
     dt = table.coeffs.dtype
     lq = jnp.maximum(jnp.asarray(lq, dt), table.seg_lo[0])
     uq = jnp.maximum(jnp.asarray(uq, dt), table.seg_lo[0])
@@ -95,7 +96,7 @@ def range_sum(table: IndexPlan, lq, uq, backend: str = "pallas",
 @functools.partial(jax.jit, static_argnames=("backend", "bq", "bh", "interpret"))
 def range_max(table: IndexPlan, lq, uq, backend: str = "pallas",
               bq: int = DEFAULT_BQ, bh: int = DEFAULT_BH,
-              interpret: bool = True):
+              interpret: Optional[bool] = None):
     dt = table.coeffs.dtype
     lq = jnp.maximum(jnp.asarray(lq, dt), table.seg_lo[0])
     uq = jnp.maximum(jnp.asarray(uq, dt), table.seg_lo[0])

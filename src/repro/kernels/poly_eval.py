@@ -29,6 +29,7 @@ comfortably inside the ~16 MiB VMEM budget with MXU-aligned dims.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -37,10 +38,21 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core.poly import horner, scale_unit
 
-__all__ = ["poly_eval_pallas", "DEFAULT_BQ", "DEFAULT_BH"]
+__all__ = ["poly_eval_pallas", "resolve_interpret", "DEFAULT_BQ",
+           "DEFAULT_BH"]
 
 DEFAULT_BQ = 256
 DEFAULT_BH = 512
+
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """Pallas interpret mode, decided from the platform: kernels run in the
+    interpreter exactly when the default backend is the CPU, and compile
+    for the chip everywhere else.  ``None`` (every default in this package)
+    asks the platform; an explicit bool is kept as given."""
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return bool(interpret)
 
 
 def _poly_eval_kernel(q_ref, lo_ref, nxt_ref, hi_ref, coef_ref, out_ref,
@@ -74,7 +86,7 @@ def _poly_eval_kernel(q_ref, lo_ref, nxt_ref, hi_ref, coef_ref, out_ref,
 
 def poly_eval_pallas(q, seg_lo, seg_next, seg_hi, coeffs,
                      bq: int = DEFAULT_BQ, bh: int = DEFAULT_BH,
-                     interpret: bool = True):
+                     interpret: Optional[bool] = None):
     """P_{I(q)}(q) for q (Q,) against H segments.  Shapes must be padded to
     block multiples by the caller (see ops.pad_index / ops.poly_eval)."""
     Q, H = q.shape[0], seg_lo.shape[0]
@@ -99,5 +111,5 @@ def poly_eval_pallas(q, seg_lo, seg_next, seg_hi, coeffs,
             pltpu.VMEM((bq,), coeffs.dtype),
             pltpu.VMEM((bq,), coeffs.dtype),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, seg_lo, seg_next, seg_hi, coeffs)

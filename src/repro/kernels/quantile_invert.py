@@ -25,13 +25,14 @@ because the slack is a traced scalar.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..core.quantile import certified_quantile_shifted
-from .poly_eval import DEFAULT_BQ
+from .poly_eval import DEFAULT_BQ, resolve_interpret
 
 __all__ = ["quantile_invert_pallas"]
 
@@ -55,7 +56,8 @@ def quantile_invert_pallas(t_mid: jnp.ndarray, t_lo: jnp.ndarray,
                            coeffs: jnp.ndarray, seg_err: jnp.ndarray,
                            ref_keys: jnp.ndarray, *, h: int, n: int,
                            delta: float, bq: int = DEFAULT_BQ,
-                           interpret: bool = True, scan: bool = False):
+                           interpret: Optional[bool] = None,
+                           scan: bool = False):
     """(answer, lower, upper) for slack-pre-shifted rank-target blocks.
 
     ``ref_keys`` is the (padded) sorted exact key grid; ``n`` the live
@@ -80,5 +82,5 @@ def quantile_invert_pallas(t_mid: jnp.ndarray, t_lo: jnp.ndarray,
                   pl.BlockSpec((nk,), lambda i: (0,))],
         out_specs=(qspec, qspec, qspec),
         out_shape=(out, out, out),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(t_mid, t_lo, t_hi, B, seg_lo, seg_hi, coeffs, seg_err, ref_keys)

@@ -18,6 +18,7 @@ served on negated aggregates, so answers are bit-identical across paths.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +27,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core.poly import clipped_poly_max
 from .locate import locate_segments, rmq_gather
-from .poly_eval import DEFAULT_BH, DEFAULT_BQ
+from .poly_eval import DEFAULT_BH, DEFAULT_BQ, resolve_interpret
 
 __all__ = ["range_max_pallas", "range_max_gather_pallas"]
 
@@ -60,7 +61,8 @@ def _range_max_gather_kernel(lq_ref, uq_ref, lo_ref, hi_ref, coef_ref,
 
 
 def range_max_gather_pallas(lq, uq, seg_lo, seg_hi, coeffs, st,
-                            bq: int = DEFAULT_BQ, interpret: bool = True):
+                            bq: int = DEFAULT_BQ,
+                            interpret: Optional[bool] = None):
     """Locate->gather range MAX; ``st`` is the plan's (L, h) sparse table
     over per-segment aggregates (unpadded — in-domain queries never locate
     the sentinel tail)."""
@@ -85,7 +87,7 @@ def range_max_gather_pallas(lq, uq, seg_lo, seg_hi, coeffs, st,
         ],
         out_specs=pl.BlockSpec((bq,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((Q,), coeffs.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lq, uq, seg_lo, seg_hi, coeffs, st)
 
 
@@ -141,7 +143,7 @@ def _range_max_kernel(lq_ref, uq_ref, lo_ref, nxt_ref, hi_ref, coef_ref,
 
 def range_max_pallas(lq, uq, seg_lo, seg_next, seg_hi, coeffs, seg_agg,
                      bq: int = DEFAULT_BQ, bh: int = DEFAULT_BH,
-                     interpret: bool = True):
+                     interpret: Optional[bool] = None):
     Q, H = lq.shape[0], seg_lo.shape[0]
     assert Q % bq == 0 and H % bh == 0, (Q, H, bq, bh)
     deg = coeffs.shape[1] - 1
@@ -166,5 +168,5 @@ def range_max_pallas(lq, uq, seg_lo, seg_next, seg_hi, coeffs, seg_agg,
             pltpu.VMEM((bq, 2 * (deg + 3)), coeffs.dtype),
             pltpu.VMEM((bq,), coeffs.dtype),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lq, uq, seg_lo, seg_next, seg_hi, coeffs, seg_agg)

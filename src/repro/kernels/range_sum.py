@@ -18,6 +18,7 @@ Both paths gather the same rows and share ``core.poly.horner``/
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +27,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core.poly import horner, scale_unit
 from .locate import locate_segments
-from .poly_eval import DEFAULT_BH, DEFAULT_BQ
+from .poly_eval import DEFAULT_BH, DEFAULT_BQ, resolve_interpret
 
 __all__ = ["range_sum_pallas", "range_sum_gather_pallas"]
 
@@ -47,7 +48,8 @@ def _range_sum_gather_kernel(lq_ref, uq_ref, lo_ref, hi_ref, coef_ref,
 
 
 def range_sum_gather_pallas(lq, uq, seg_lo, seg_hi, coeffs,
-                            bq: int = DEFAULT_BQ, interpret: bool = True):
+                            bq: int = DEFAULT_BQ,
+                            interpret: Optional[bool] = None):
     """Locate->gather range SUM: grid over query blocks only, the whole
     (sentinel-padded) segment table resident per block."""
     Q, H = lq.shape[0], seg_lo.shape[0]
@@ -65,7 +67,7 @@ def range_sum_gather_pallas(lq, uq, seg_lo, seg_hi, coeffs,
         ],
         out_specs=pl.BlockSpec((bq,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((Q,), coeffs.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lq, uq, seg_lo, seg_hi, coeffs)
 
 
@@ -107,7 +109,7 @@ def _range_sum_kernel(lq_ref, uq_ref, lo_ref, nxt_ref, hi_ref, coef_ref,
 
 def range_sum_pallas(lq, uq, seg_lo, seg_next, seg_hi, coeffs,
                      bq: int = DEFAULT_BQ, bh: int = DEFAULT_BH,
-                     interpret: bool = True):
+                     interpret: Optional[bool] = None):
     Q, H = lq.shape[0], seg_lo.shape[0]
     assert Q % bq == 0 and H % bh == 0, (Q, H, bq, bh)
     deg = coeffs.shape[1] - 1
@@ -127,5 +129,5 @@ def range_sum_pallas(lq, uq, seg_lo, seg_next, seg_hi, coeffs,
         out_specs=pl.BlockSpec((bq,), lambda i, j: (i,)),
         out_shape=jax.ShapeDtypeStruct((Q,), coeffs.dtype),
         scratch_shapes=[pltpu.VMEM((bq, 2 * (deg + 3)), coeffs.dtype)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(lq, uq, seg_lo, seg_next, seg_hi, coeffs)

@@ -24,7 +24,7 @@ import numpy as np  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from ..configs import ARCHS, SHAPES  # noqa: E402
-from ..dist.sharding import (batch_specs, cache_specs, mesh_context, named,  # noqa: E402
+from ..dist.sharding import (batch_specs, cache_specs, named,  # noqa: E402
                              param_specs, state_specs)
 from ..launch.mesh import dp_axes, make_production_mesh  # noqa: E402
 from ..models import init_cache, init_model  # noqa: E402
@@ -170,7 +170,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         pspecs = param_specs(params_abs, mesh)
         bspecs = batch_specs(cfg, shape, mesh)
 
-        with mesh_context(mesh):
+        with jax.sharding.set_mesh(mesh):
             if shape.kind == "train":
                 state_abs = jax.eval_shape(adamw_init, params_abs)
                 sspecs = state_specs(params_abs, mesh)

@@ -20,8 +20,7 @@ from ..configs import ARCHS, SHAPES
 from ..data.pipeline import SyntheticTokens
 from ..dist.fault_tolerance import (FailureInjector, HeartbeatMonitor,
                                     SimulatedPodFailure, elastic_remesh)
-from ..dist.sharding import (batch_specs, mesh_context, param_specs,
-                             state_specs)
+from ..dist.sharding import batch_specs, param_specs, state_specs
 from ..models import init_model
 from ..optim import adamw_init
 from ..train import make_train_step
@@ -88,7 +87,7 @@ def main(argv=None):
     while step < args.steps:
         try:
             injector.check(step)
-            with mesh_context(mesh):
+            with jax.sharding.set_mesh(mesh):
                 batch = pipe.sharded_batch(step, bshard)
                 state, metrics = train_step(state, batch)
             msg = monitor.beat()

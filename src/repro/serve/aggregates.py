@@ -30,7 +30,71 @@ from ..api import ErrorBudget, PolyFit, QuerySpec, TableSpec
 from ..data import hki_series, osm_points, tweet_latitudes
 from .engine import ServingEngine
 
-__all__ = ["AggregateService"]
+__all__ = ["AggregateService", "aggregate_tables"]
+
+
+def aggregate_tables(n1: int, n2: int, *, eps_abs: float = 100.0,
+                     eps_rel: Optional[float] = 0.01,
+                     guarantees: Optional[Dict[str, Tuple]] = None, **kw):
+    """The service's eight tables over the paper's synthetic datasets:
+    ``(datasets, specs, domains)`` ready for ``PolyFit.fit``.
+
+    1-D kinds read ``n1`` TWEET latitudes ('count') or HKI minute bars
+    ('sum'/'max'/'min'); 2-D kinds read ``n2`` OSM points, with synthetic
+    per-node weights for 'sum2d'/'max2d'/'min2d'.  ``kw`` (``dynamic``,
+    ``capacity``, ``background``, ``shards``, ...) goes to every
+    ``TableSpec``; ``guarantees`` maps a kind to its ``(deadline_s,
+    priority)`` serving class.  ``domains`` holds each kind's key box
+    (x0, x1[, y0, y1]) — the dominance kinds keep only the upper corner.
+    """
+    lat = tweet_latitudes(n1)
+    ts, vals = hki_series(n1)
+    px, py = osm_points(n2)
+    # synthetic per-node weights for the 2-D measure tables
+    pw = 50.0 + 20.0 * np.sin(px / 7.0) + 15.0 * np.cos(py / 11.0)
+
+    budget = ErrorBudget(abs=eps_abs, rel=eps_rel)
+    # weighted sums run ~mean(w) larger than counts at the same shape,
+    # so the SUM/SUM2D budgets scale the COUNT one to matching
+    # *relative* tightness (the absolute bound is still certified,
+    # just in measure units); extremum answers live on the measure
+    # *spread*, so their budgets are a fraction of that — reusing the
+    # count-unit eps_abs would exceed the whole spread and certify a
+    # trivial one-leaf fit
+    sbudget = ErrorBudget(abs=eps_abs * float(np.abs(vals).mean()),
+                          rel=eps_rel)
+    vbudget = ErrorBudget(abs=0.1 * float(vals.max() - vals.min()),
+                          rel=eps_rel)
+    wbudget = ErrorBudget(abs=eps_abs * float(pw.mean()), rel=eps_rel)
+    mbudget = ErrorBudget(abs=0.1 * float(pw.max() - pw.min()),
+                          rel=eps_rel)
+
+    # per-kind serving guarantee classes: {kind: (deadline_s, priority)}
+    # become the engine's admission-deadline / shed-ladder defaults
+    def spec(agg, b, deg):
+        d, p = (guarantees or {}).get(agg, (None, 0))
+        return TableSpec(agg, b, deg=deg, deadline=d, priority=p, **kw)
+
+    datasets = {"count": lat, "sum": (ts, vals), "max": (ts, vals),
+                "min": (ts, vals), "count2d": (px, py),
+                "sum2d": (px, py, pw), "max2d": (px, py, pw),
+                "min2d": (px, py, pw)}
+    specs = {"count": spec("count", budget, 2),
+             "sum": spec("sum", sbudget, 2),
+             "max": spec("max", vbudget, 3),
+             "min": spec("min", vbudget, 3),
+             "count2d": spec("count2d", budget, 3),
+             "sum2d": spec("sum2d", wbudget, 3),
+             "max2d": spec("max2d", mbudget, 3),
+             "min2d": spec("min2d", mbudget, 3)}
+    dom1 = (float(ts.min()), float(ts.max()))
+    dom2 = (float(px.min()), float(px.max()),
+            float(py.min()), float(py.max()))
+    domains = {"count": (float(lat.min()), float(lat.max())),
+               "sum": dom1, "max": dom1, "min": dom1,
+               "count2d": dom2, "sum2d": dom2,
+               "max2d": dom2[1::2], "min2d": dom2[1::2]}
+    return datasets, specs, domains
 
 
 class AggregateService:
@@ -59,7 +123,7 @@ class AggregateService:
 
     def __init__(self, backend: str = "xla", eps_abs: float = 100.0,
                  eps_rel: Optional[float] = 0.01, n1: int = 150_000,
-                 n2: int = 60_000, interpret: bool = True,
+                 n2: int = 60_000, interpret: Optional[bool] = None,
                  verbose: bool = True, dynamic: bool = False,
                  capacity: int = 1024, shards: Optional[int] = None,
                  max_queue: int = 1024, workers: int = 1,
@@ -75,64 +139,12 @@ class AggregateService:
         say(f"[server] building indexes (backend={backend}, "
             f"dynamic={dynamic}, shards={shards}) ...")
         t0 = time.time()
-        lat = tweet_latitudes(n1)
-        ts, vals = hki_series(n1)
-        px, py = osm_points(n2)
-        # synthetic per-node weights for the 2-D measure tables
-        pw = 50.0 + 20.0 * np.sin(px / 7.0) + 15.0 * np.cos(py / 11.0)
-
-        budget = ErrorBudget(abs=eps_abs, rel=eps_rel)
-        # weighted sums run ~mean(w) larger than counts at the same shape,
-        # so the SUM/SUM2D budgets scale the COUNT one to matching
-        # *relative* tightness (the absolute bound is still certified,
-        # just in measure units); extremum answers live on the measure
-        # *spread*, so their budgets are a fraction of that — reusing the
-        # count-unit eps_abs would exceed the whole spread and certify a
-        # trivial one-leaf fit
-        sbudget = ErrorBudget(abs=eps_abs * float(np.abs(vals).mean()),
-                              rel=eps_rel)
-        vbudget = ErrorBudget(abs=0.1 * float(vals.max() - vals.min()),
-                              rel=eps_rel)
-        wbudget = ErrorBudget(abs=eps_abs * float(pw.mean()), rel=eps_rel)
-        mbudget = ErrorBudget(abs=0.1 * float(pw.max() - pw.min()),
-                              rel=eps_rel)
-        kw = dict(dynamic=dynamic, capacity=capacity, background=True,
-                  shards=shards)
-
-        # per-kind serving guarantee classes: {kind: (deadline_s, priority)}
-        # become the engine's admission-deadline / shed-ladder defaults
-        def klass(kind):
-            d, p = (guarantees or {}).get(kind, (None, 0))
-            return dict(deadline=d, priority=p)
-        self.session = PolyFit.fit(
-            {"count": lat, "sum": (ts, vals), "max": (ts, vals),
-             "min": (ts, vals), "count2d": (px, py),
-             "sum2d": (px, py, pw), "max2d": (px, py, pw),
-             "min2d": (px, py, pw)},
-            {"count": TableSpec("count", budget, deg=2, **kw,
-                                **klass("count")),
-             "sum": TableSpec("sum", sbudget, deg=2, **kw, **klass("sum")),
-             "max": TableSpec("max", vbudget, deg=3, **kw, **klass("max")),
-             "min": TableSpec("min", vbudget, deg=3, **kw, **klass("min")),
-             "count2d": TableSpec("count2d", budget, deg=3, **kw,
-                                  **klass("count2d")),
-             "sum2d": TableSpec("sum2d", wbudget, deg=3, **kw,
-                                **klass("sum2d")),
-             "max2d": TableSpec("max2d", mbudget, deg=3, **kw,
-                                **klass("max2d")),
-             "min2d": TableSpec("min2d", mbudget, deg=3, **kw,
-                                **klass("min2d"))},
-            backend=backend, interpret=interpret)
-
-        dom1 = (float(ts.min()), float(ts.max()))
-        dom2 = (float(px.min()), float(px.max()),
-                float(py.min()), float(py.max()))
-        self.domains: Dict[str, Tuple[float, ...]] = {
-            "count": (float(lat.min()), float(lat.max())),
-            "sum": dom1, "max": dom1, "min": dom1,
-            "count2d": dom2, "sum2d": dom2,
-            "max2d": dom2[1::2], "min2d": dom2[1::2],
-        }
+        datasets, specs, self.domains = aggregate_tables(
+            n1, n2, eps_abs=eps_abs, eps_rel=eps_rel, guarantees=guarantees,
+            dynamic=dynamic, capacity=capacity, background=True,
+            shards=shards)
+        self.session = PolyFit.fit(datasets, specs, backend=backend,
+                                   interpret=interpret)
         self.engine = ServingEngine(self.session, max_queue=max_queue,
                                     workers=workers, admission=admission,
                                     start=start, injector=injector,

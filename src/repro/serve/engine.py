@@ -135,6 +135,7 @@ class EngineStats:
     aot_invalidations: int = 0  # cache entries dropped on plan swap
     aot_precompiles: int = 0  # executables staged on the merge thread
     aot_promotions: int = 0   # staged executables promoted at dispatch
+    aot_precompile_failures: int = 0  # merge-thread pre-compiles that raised
     staged_records: int = 0   # update records accepted into the journal
     drains: int = 0           # updater wake-ups that applied work
     fused_applies: int = 0    # engine insert/delete calls made by drains
@@ -916,7 +917,10 @@ class ServingEngine:
             try:
                 self._precompile(table, incoming)
             except Exception:
-                pass   # fall back to lazy recompile; never abort an install
+                # never abort an install: the first post-swap dispatch
+                # relowers lazily, and the failure stays visible here
+                with self._stats_lock:
+                    self._stats.aot_precompile_failures += 1
         return listener
 
     def _precompile(self, table: str, incoming) -> None:

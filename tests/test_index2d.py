@@ -112,6 +112,28 @@ def test_weighted_mst_exact(wdata):
     np.testing.assert_array_equal(t.dommax_np(qu, qv), want_max)
 
 
+def test_host_oracles_match_device_twins(wdata):
+    """The construction-time host oracles (one searchsorted per level over
+    rank keys) equal the device twins' unrolled binary searches exactly,
+    ties included: queries sit on data coordinates and on duplicates."""
+    px, py, w = wdata
+    px = np.concatenate([px, px[:50]])          # duplicate points
+    py = np.concatenate([py, py[:50]])
+    w = np.concatenate([w, w[:50] + 1.0])
+    t = MergeSortTree.build(px, py, ws=w)
+    rng = np.random.default_rng(2)
+    qu = np.concatenate([rng.uniform(-5, 105, 100), px[:100],
+                         [px.min(), px.max()]])
+    qv = np.concatenate([rng.uniform(-5, 105, 100), py[100:200],
+                         [py.max(), py.min()]])
+    ju, jv = jnp.asarray(qu), jnp.asarray(qv)
+    np.testing.assert_array_equal(t.cf_np(qu, qv), np.asarray(t.cf(ju, jv)))
+    np.testing.assert_array_equal(t.cf_sum_np(qu, qv),
+                                  np.asarray(t.cf_sum(ju, jv)))
+    np.testing.assert_array_equal(t.dommax_np(qu, qv),
+                                  np.asarray(t.dommax(ju, jv)))
+
+
 def test_unweighted_mst_unchanged(wdata):
     """Weight-free build keeps the old layout (no weighted arrays)."""
     px, py, _ = wdata
