@@ -404,8 +404,9 @@ def _exec_dyn_sum(plan: IndexPlan, buf: DeltaBuffer, lq, uq, *, backend: str,
     lqr, uqr = lq.astype(dt), uq.astype(dt)
     lqc = jnp.maximum(lqr, plan.domain_lo)
     uqc = jnp.maximum(uqr, plan.domain_lo)
-    static = raw_sum(plan, lqc, uqc, backend=backend, interpret=interpret,
-                     bq=bq)
+    with jax.named_scope("approx"):
+        static = raw_sum(plan, lqc, uqc, backend=backend,
+                         interpret=interpret, bq=bq)
     # exact correction over (lq, uq] — unclamped: buffered keys may lie
     # outside the static domain
     corr = (_delta_sum(lqr, uqr, buf.ins_keys, buf.ins_vals, buf.ins_cf,
@@ -420,7 +421,8 @@ def _exec_dyn_sum(plan: IndexPlan, buf: DeltaBuffer, lq, uq, *, backend: str,
     two_d = 2.0 * plan.delta
     ok = ((approx - two_d > 0) &
           (two_d / jnp.maximum(approx - two_d, 1e-300) <= eps_rel))
-    truth = truth_sum(plan, lqr, uqr) + corr
+    with jax.named_scope("refine"):
+        truth = truth_sum(plan, lqr, uqr) + corr
     return jnp.where(ok, approx, truth), approx, ~ok
 
 
@@ -537,8 +539,9 @@ def _exec_dyn_extremum(plan: IndexPlan, buf: DeltaBuffer, lq, uq, *,
     lqr, uqr = lq.astype(dt), uq.astype(dt)
     lqc = jnp.maximum(lqr, plan.domain_lo)
     uqc = jnp.maximum(uqr, plan.domain_lo)
-    static = raw_extremum(plan, lqc, uqc, backend=backend,
-                          interpret=interpret, bq=bq)
+    with jax.named_scope("approx"):
+        static = raw_extremum(plan, lqc, uqc, backend=backend,
+                              interpret=interpret, bq=bq)
     ins = _delta_max(lqr, uqr, buf.ins_keys, buf.ins_vals, buf.ins_st,
                      backend=backend, interpret=interpret, bq=bq)
     approx = jnp.maximum(static, ins)
@@ -547,9 +550,10 @@ def _exec_dyn_extremum(plan: IndexPlan, buf: DeltaBuffer, lq, uq, *,
         # victim-shadowed path: a range covering a deleted base row cannot
         # trust the fitted approximation (the victim may be the maximum) —
         # refine against the victim-masked exact sparse table instead
-        i0 = jnp.searchsorted(plan.ref_keys, lqr, side="left")
-        i1 = jnp.searchsorted(plan.ref_keys, uqr, side="right")
-        base_exact = sparse_table_range_max(buf.live_st, i0, i1)
+        with jax.named_scope("refine"):
+            i0 = jnp.searchsorted(plan.ref_keys, lqr, side="left")
+            i1 = jnp.searchsorted(plan.ref_keys, uqr, side="right")
+            base_exact = sparse_table_range_max(buf.live_st, i0, i1)
         exact = jnp.maximum(base_exact, ins)
         vk = buf.vic_keys
         threat = jnp.any((lqr[:, None] <= vk[None, :]) &
@@ -569,7 +573,8 @@ def _exec_dyn_extremum(plan: IndexPlan, buf: DeltaBuffer, lq, uq, *,
         return out, out, jnp.zeros(out.shape, bool)
     # Lemma 5.4: max(static +- delta, exact) stays within delta of the truth
     ok = approx >= plan.delta * (1.0 + 1.0 / eps_rel)
-    truth = jnp.maximum(truth_extremum(plan, lqr, uqr), ins)
+    with jax.named_scope("refine"):
+        truth = jnp.maximum(truth_extremum(plan, lqr, uqr), ins)
     ans = jnp.where(ok, approx, truth)
     if neg:
         ans, approx = -ans, -approx
@@ -585,8 +590,9 @@ def _exec_dyn_count2d(plan: IndexPlan2D, buf: DeltaBuffer2D, lx, ux, ly, uy,
     lxr, uxr, lyr, uyr = (q.astype(dt) for q in (lx, ux, ly, uy))
     lxc, uxc = (jnp.clip(q, x0, x1) for q in (lxr, uxr))
     lyc, uyc = (jnp.clip(q, y0, y1) for q in (lyr, uyr))
-    static = raw_count2d(plan, lxc, uxc, lyc, uyc, backend=backend,
-                         interpret=interpret, bq=bq)
+    with jax.named_scope("approx"):
+        static = raw_count2d(plan, lxc, uxc, lyc, uyc, backend=backend,
+                             interpret=interpret, bq=bq)
     corr = (_delta_count2d(lxr, uxr, lyr, uyr, buf.ins_x, buf.ins_y,
                            buf.ins_ylv, backend=backend, interpret=interpret,
                            bq=bq, dtype=dt)
@@ -597,7 +603,8 @@ def _exec_dyn_count2d(plan: IndexPlan2D, buf: DeltaBuffer2D, lx, ux, ly, uy,
     if eps_rel is None:
         return approx, approx, jnp.zeros(approx.shape, bool)
     ok = approx >= 4.0 * plan.delta * (1.0 + 1.0 / eps_rel)   # Lemma 6.4
-    truth = truth_count2d(plan, lxr, uxr, lyr, uyr) + corr
+    with jax.named_scope("refine"):
+        truth = truth_count2d(plan, lxr, uxr, lyr, uyr) + corr
     return jnp.where(ok, approx, truth), approx, ~ok
 
 
@@ -610,8 +617,9 @@ def _exec_dyn_sum2d(plan: IndexPlan2D, buf: DeltaBuffer2D, lx, ux, ly, uy,
     lxr, uxr, lyr, uyr = (q.astype(dt) for q in (lx, ux, ly, uy))
     lxc, uxc = (jnp.clip(q, x0, x1) for q in (lxr, uxr))
     lyc, uyc = (jnp.clip(q, y0, y1) for q in (lyr, uyr))
-    static = raw_count2d(plan, lxc, uxc, lyc, uyc, backend=backend,
-                         interpret=interpret, bq=bq)
+    with jax.named_scope("approx"):
+        static = raw_count2d(plan, lxc, uxc, lyc, uyc, backend=backend,
+                             interpret=interpret, bq=bq)
     # exact weighted correction — unclamped: buffered points may lie
     # outside the static root rectangle
     corr = (_delta_sum2d(lxr, uxr, lyr, uyr, buf.ins_x, buf.ins_y,
@@ -624,7 +632,8 @@ def _exec_dyn_sum2d(plan: IndexPlan2D, buf: DeltaBuffer2D, lx, ux, ly, uy,
     if eps_rel is None:
         return approx, approx, jnp.zeros(approx.shape, bool)
     ok = approx >= 4.0 * plan.delta * (1.0 + 1.0 / eps_rel)   # Lemma 6.4
-    truth = truth_sum2d(plan, lxr, uxr, lyr, uyr) + corr
+    with jax.named_scope("refine"):
+        truth = truth_sum2d(plan, lxr, uxr, lyr, uyr) + corr
     return jnp.where(ok, approx, truth), approx, ~ok
 
 
@@ -640,8 +649,9 @@ def _exec_dyn_dommax2d(plan: IndexPlan2D, buf: DeltaBuffer2D, u, v, *,
     ur, vr = u.astype(dt), v.astype(dt)
     uc = jnp.clip(ur, x0, x1)
     vc = jnp.clip(vr, y0, y1)
-    static = raw_eval2d(plan, uc, vc, backend=backend, interpret=interpret,
-                        bq=bq)
+    with jax.named_scope("approx"):
+        static = raw_eval2d(plan, uc, vc, backend=backend,
+                            interpret=interpret, bq=bq)
     ins = _delta_dommax2d(ur, vr, buf.ins_x, buf.ins_y, buf.ins_w,
                           buf.ins_ylv, buf.ins_wpmax, backend=backend,
                           interpret=interpret, bq=bq)
@@ -650,8 +660,9 @@ def _exec_dyn_dommax2d(plan: IndexPlan2D, buf: DeltaBuffer2D, u, v, *,
     if buf.vic_x is not None:
         # victim-shadowed path: refine dominance corners that cover a
         # deleted base point against the victim-masked merge-sort tree
-        base_exact = mst_dommax(plan.ref_xs, plan.ref_ys_levels,
-                                buf.live_wpmax, ur, vr)
+        with jax.named_scope("refine"):
+            base_exact = mst_dommax(plan.ref_xs, plan.ref_ys_levels,
+                                    buf.live_wpmax, ur, vr)
         exact = jnp.maximum(base_exact.astype(dt), ins)
         threat = jnp.any((buf.vic_x[None, :] <= ur[:, None]) &
                          (buf.vic_y[None, :] <= vr[:, None]), axis=1)
@@ -669,7 +680,8 @@ def _exec_dyn_dommax2d(plan: IndexPlan2D, buf: DeltaBuffer2D, u, v, *,
         out = -approx if neg else approx
         return out, out, jnp.zeros(out.shape, bool)
     ok = approx >= plan.delta * (1.0 + 1.0 / eps_rel)
-    truth = jnp.maximum(truth_dommax2d(plan, ur, vr), ins)
+    with jax.named_scope("refine"):
+        truth = jnp.maximum(truth_dommax2d(plan, ur, vr), ins)
     ans = jnp.where(ok, approx, truth)
     if neg:
         ans, approx = -ans, -approx

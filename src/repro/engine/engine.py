@@ -251,15 +251,17 @@ def _exec_sum(plan: IndexPlan, lq, uq, *, backend: str,
     dt = plan.dtype
     lqc = jnp.maximum(lq.astype(dt), plan.domain_lo)
     uqc = jnp.maximum(uq.astype(dt), plan.domain_lo)
-    approx = raw_sum(plan, lqc, uqc, backend=backend, interpret=interpret,
-                     bq=bq)
+    with jax.named_scope("approx"):
+        approx = raw_sum(plan, lqc, uqc, backend=backend,
+                         interpret=interpret, bq=bq)
     if eps_rel is None:
         return approx, approx, jnp.zeros(approx.shape, bool)
     # Lemma 5.2 test: 2d / (A - 2d) <= eps_rel  (requires A > 2d)
     two_d = 2.0 * plan.delta
     ok = ((approx - two_d > 0) &
           (two_d / jnp.maximum(approx - two_d, 1e-300) <= eps_rel))
-    truth = truth_sum(plan, lq, uq)
+    with jax.named_scope("refine"):
+        truth = truth_sum(plan, lq, uq)
     return jnp.where(ok, approx, truth), approx, ~ok
 
 
@@ -270,8 +272,9 @@ def _exec_extremum(plan: IndexPlan, lq, uq, *, backend: str,
     dt = plan.dtype
     lqc = jnp.maximum(lq.astype(dt), plan.domain_lo)
     uqc = jnp.maximum(uq.astype(dt), plan.domain_lo)
-    approx = raw_extremum(plan, lqc, uqc, backend=backend,
-                          interpret=interpret, bq=bq)
+    with jax.named_scope("approx"):
+        approx = raw_extremum(plan, lqc, uqc, backend=backend,
+                              interpret=interpret, bq=bq)
     neg = plan.agg == "min"
     if eps_rel is None:
         out = -approx if neg else approx
@@ -279,7 +282,8 @@ def _exec_extremum(plan: IndexPlan, lq, uq, *, backend: str,
     # Lemma 5.4 test: A >= delta * (1 + 1/eps_rel), in MAX space (MIN runs
     # on negated measures end to end, exactly like core.queries.query_max)
     ok = approx >= plan.delta * (1.0 + 1.0 / eps_rel)
-    truth = truth_extremum(plan, lq, uq)
+    with jax.named_scope("refine"):
+        truth = truth_extremum(plan, lq, uq)
     ans = jnp.where(ok, approx, truth)
     if neg:
         ans, approx = -ans, -approx
@@ -297,14 +301,16 @@ def _exec_rect2d(plan: IndexPlan2D, lx, ux, ly, uy, *, backend: str,
     x0, x1, y0, y1 = plan.root
     lxc, uxc = (jnp.clip(q.astype(dt), x0, x1) for q in (lx, ux))
     lyc, uyc = (jnp.clip(q.astype(dt), y0, y1) for q in (ly, uy))
-    approx = raw_count2d(plan, lxc, uxc, lyc, uyc, backend=backend,
-                         interpret=interpret, bq=bq)
+    with jax.named_scope("approx"):
+        approx = raw_count2d(plan, lxc, uxc, lyc, uyc, backend=backend,
+                             interpret=interpret, bq=bq)
     if eps_rel is None:
         return approx, approx, jnp.zeros(approx.shape, bool)
     # Lemma 6.4 test: A >= 4*delta*(1 + 1/eps_rel)
     ok = approx >= 4.0 * plan.delta * (1.0 + 1.0 / eps_rel)
-    truth = (truth_sum2d(plan, lx, ux, ly, uy) if plan.agg == "sum2d"
-             else truth_count2d(plan, lx, ux, ly, uy))
+    with jax.named_scope("refine"):
+        truth = (truth_sum2d(plan, lx, ux, ly, uy) if plan.agg == "sum2d"
+                 else truth_count2d(plan, lx, ux, ly, uy))
     return jnp.where(ok, approx, truth), approx, ~ok
 
 
@@ -318,15 +324,17 @@ def _exec_extremum2d(plan: IndexPlan2D, u, v, *, backend: str,
     x0, x1, y0, y1 = plan.root
     uc = jnp.clip(u.astype(dt), x0, x1)
     vc = jnp.clip(v.astype(dt), y0, y1)
-    approx = raw_eval2d(plan, uc, vc, backend=backend, interpret=interpret,
-                        bq=bq)
+    with jax.named_scope("approx"):
+        approx = raw_eval2d(plan, uc, vc, backend=backend,
+                            interpret=interpret, bq=bq)
     neg = plan.agg == "min2d"
     if eps_rel is None:
         out = -approx if neg else approx
         return out, out, jnp.zeros(out.shape, bool)
     # Lemma 5.4 shape: A >= delta * (1 + 1/eps_rel), in MAX space
     ok = approx >= plan.delta * (1.0 + 1.0 / eps_rel)
-    truth = truth_dommax2d(plan, u.astype(dt), v.astype(dt))
+    with jax.named_scope("refine"):
+        truth = truth_dommax2d(plan, u.astype(dt), v.astype(dt))
     ans = jnp.where(ok, approx, truth)
     if neg:
         ans, approx = -ans, -approx

@@ -82,6 +82,7 @@ through the AOT path.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 import queue
 import threading
@@ -94,6 +95,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import spans
 from ..api.session import Answer
 from ..api.spec import DEFAULT_REL, QueryBatch, QuerySpec
 from ..core.queries import QueryResult
@@ -137,7 +139,6 @@ class EngineStats:
     aot_promotions: int = 0   # staged executables promoted at dispatch
     aot_precompile_failures: int = 0  # merge-thread pre-compiles that raised
     staged_records: int = 0   # update records accepted into the journal
-    drains: int = 0           # updater wake-ups that applied work
     fused_applies: int = 0    # engine insert/delete calls made by drains
     worker_crashes: int = 0   # worker threads that died mid-batch
     updater_crashes: int = 0  # updater threads that died mid-drain
@@ -147,7 +148,7 @@ class EngineStats:
 
 class _ReadRequest:
     __slots__ = ("table", "kind", "rel", "ranges", "params", "n", "future",
-                 "deadline", "dclass", "priority")
+                 "deadline", "dclass", "priority", "rid", "t_put", "t_taken")
 
     def __init__(self, table: str, rel, ranges: Tuple, n: int,
                  deadline: Optional[float] = None,
@@ -163,6 +164,14 @@ class _ReadRequest:
         self.dclass = dclass        # pow-2 bucket of the deadline duration
         self.priority = priority
         self.future: Future = Future()
+        # the ``polyfit.serve.queued`` span, stamped only while spans are on
+        self.rid = -1
+        self.t_put = -1
+        self.t_taken = -1
+
+    def taken(self) -> None:
+        if self.t_put >= 0:
+            self.t_taken = time.perf_counter_ns()
 
 
 class _WriteItem:
@@ -263,6 +272,14 @@ def _tree_sig(x) -> Tuple:
     return treedef, tuple((l.shape, str(l.dtype)) for l in leaves)
 
 
+def _aot_compile(fn, *args):
+    """``jax.jit(fn)`` lowered for ``args`` (arrays or shapes) and compiled:
+    the one place the engine compiles, on dispatch, warm-up or a plan
+    swap."""
+    with spans.span("polyfit.aot.compile"):
+        return jax.jit(fn).lower(*args).compile()
+
+
 def _tree_tmpl(x):
     """The pytree with every array leaf abstracted to ShapeDtypeStruct
     (``jax.jit(...).lower`` accepts these in place of concrete arrays)."""
@@ -324,6 +341,7 @@ class ServingEngine:
         self._drain_lock = threading.Lock()
         self._stats = EngineStats()
         self._stats_lock = threading.Lock()
+        self._request_ids = itertools.count()
         self._update_errors: List[BaseException] = []
         self._stop = threading.Event()
         self._shut_down = False
@@ -539,6 +557,9 @@ class ServingEngine:
         req = _ReadRequest(spec.table, rel, spec.ranges, len(spec),
                            abs_deadline, dclass, priority, kind=kind,
                            params=params)
+        if spans.enabled():
+            req.rid = next(self._request_ids)
+            req.t_put = time.perf_counter_ns()
         try:
             if self.admission == "reject":
                 self._queue.put_nowait(req)
@@ -600,26 +621,29 @@ class ServingEngine:
                 if self._stop.is_set():
                     return
                 continue
+            req.taken()
             batch = [req]
             try:
-                # chaos site: a crash here has requests in flight — fail
-                # exactly those futures, account the queue, then die
-                self._maybe_fail("serve.worker")
-                budget = self.max_batch - req.n
-                while budget > 0:
-                    # peek so the admission batch never overshoots
-                    # max_batch — overshoot would hit a bucket above the
-                    # warmed ladder
-                    with q.mutex:
-                        if not q.queue or q.queue[0].n > budget:
+                with spans.span("polyfit.serve.batch"):
+                    # chaos site: a crash here has requests in flight — fail
+                    # exactly those futures, account the queue, then die
+                    self._maybe_fail("serve.worker")
+                    budget = self.max_batch - req.n
+                    while budget > 0:
+                        # peek so the admission batch never overshoots
+                        # max_batch — overshoot would hit a bucket above the
+                        # warmed ladder
+                        with q.mutex:
+                            if not q.queue or q.queue[0].n > budget:
+                                break
+                        try:
+                            nxt = q.get_nowait()
+                        except queue.Empty:
                             break
-                    try:
-                        nxt = q.get_nowait()
-                    except queue.Empty:
-                        break
-                    batch.append(nxt)
-                    budget -= nxt.n
-                self._process_batch(batch)
+                        nxt.taken()
+                        batch.append(nxt)
+                        budget -= nxt.n
+                    self._process_batch(batch)
             except BaseException as e:
                 for r in batch:
                     if not r.future.done():
@@ -665,15 +689,21 @@ class ServingEngine:
             # complete must also see it reflected in ``stats``
             with self._stats_lock:
                 self._stats.dispatches += 1
+                did = self._stats.dispatches
                 self._stats.answered += len(grp)
                 if len(grp) > 1:
                     self._stats.coalesced += len(grp)
+            for r in grp:
+                if r.t_put >= 0:
+                    spans.record("polyfit.serve.queued", r.t_put, r.t_taken,
+                                 request=r.rid, dispatch=did)
             try:
-                if self._retry is not None:
-                    self._retry.call(self._dispatch, table, kind, rel,
-                                     params, grp)
-                else:
-                    self._dispatch(table, kind, rel, params, grp)
+                with spans.span("polyfit.serve.dispatch", dispatch=did):
+                    if self._retry is not None:
+                        self._retry.call(self._dispatch, table, kind, rel,
+                                         params, grp)
+                    else:
+                        self._dispatch(table, kind, rel, params, grp)
             except BaseException as e:   # surface on the callers
                 for r in grp:
                     if not r.future.done():
@@ -682,11 +712,21 @@ class ServingEngine:
     def _dispatch(self, table: str, kind: str, rel, params: Tuple,
                   grp: List[_ReadRequest]) -> None:
         self._maybe_fail("serve.dispatch")
-        sess = self.session
         staleness = self.staleness(table)
         if staleness:
             with self._stats_lock:
                 self._stats.stale_reads += len(grp)
+        res = self._execute(table, kind, rel, params, grp, staleness)
+        with spans.span("polyfit.serve.device_wait"):
+            jax.block_until_ready(res.answer)   # futures resolve device-ready
+        with spans.span("polyfit.serve.scatter"):
+            self._scatter(grp, res, staleness)
+
+    def _execute(self, table: str, kind: str, rel, params: Tuple,
+                 grp: List[_ReadRequest], staleness: int):
+        """Start one group's device work; returns its answers, which may
+        not be ready yet."""
+        sess = self.session
         nq = sum(r.n for r in grp)
         size = _bucket_size(nq, sess.min_bucket)
         if kind == "window":
@@ -695,70 +735,67 @@ class ServingEngine:
             # AOT machinery (sealed epochs never invalidate their entries)
             plan, buf = sess.window_snapshot(table, *params)
             bound = sess.window_bound(table, *params)
-            if plan is None:
-                res = sess.query(QuerySpec(table, self._concat_ranges(grp),
-                                           rel, kind="window",
-                                           params=params))
-            else:
-                res = execute_lsm(plan, buf, self._concat_ranges(grp),
-                                  backend=sess.backend, eps_rel=rel,
-                                  interpret=sess.interpret, bq=sess.bq,
-                                  min_bucket=sess.min_bucket,
+            with spans.span("polyfit.serve.prepare"):
+                ranges = self._concat_ranges(grp)
+            with spans.span("polyfit.serve.execute"):
+                if plan is None:
+                    return sess.query(QuerySpec(table, ranges, rel,
+                                                kind="window",
+                                                params=params))
+                res = execute_lsm(plan, buf, ranges, backend=sess.backend,
+                                  eps_rel=rel, interpret=sess.interpret,
+                                  bq=sess.bq, min_bucket=sess.min_bucket,
                                   level_runner=self._lsm_runner(
                                       table, rel, size, plan))
-                res = Answer(res.answer, res.approx, res.refined,
-                             bound=bound, staleness=staleness)
-            jax.block_until_ready(res.answer)
-            self._scatter(grp, res, staleness)
-            return
+            return Answer(res.answer, res.approx, res.refined, bound=bound,
+                          staleness=staleness)
         if sess.is_sharded(table):
             # shard_map executors keep their own cache; no AOT ladder here
-            ranges = self._concat_ranges(grp)
-            res = sess.query(QuerySpec(table, ranges, rel, kind=kind,
-                                       params=params))
-            jax.block_until_ready(res.answer)
-            self._scatter(grp, res, staleness)
-            return
+            with spans.span("polyfit.serve.prepare"):
+                ranges = self._concat_ranges(grp)
+            with spans.span("polyfit.serve.execute"):
+                return sess.query(QuerySpec(table, ranges, rel, kind=kind,
+                                            params=params))
         plan, buf = sess.snapshot(table)
         if kind == "quantile":
-            compiled = self._executable(table, rel, size, plan, buf,
-                                        kind="quantile")
-            (qs,) = self._concat_ranges(grp)
-            qp = _pad_bucket(jnp.asarray(qs, plan.dtype), size,
-                             jnp.asarray(0.5, plan.dtype))
-            ans, lo, hi = compiled(plan, buf, qp)
-            jax.block_until_ready(ans)
-            res = Answer(ans, ans, jnp.zeros(ans.shape, bool),
-                         bound=(lo, hi), staleness=staleness)
-            self._scatter(grp, res, staleness)
-            return
+            with spans.span("polyfit.aot.lookup"):
+                compiled = self._executable(table, rel, size, plan, buf,
+                                            kind="quantile")
+            with spans.span("polyfit.serve.prepare"):
+                (qs,) = self._concat_ranges(grp)
+                qp = _pad_bucket(jnp.asarray(qs, plan.dtype), size,
+                                 jnp.asarray(0.5, plan.dtype))
+            with spans.span("polyfit.serve.execute"):
+                ans, lo, hi = compiled(plan, buf, qp)
+            return Answer(ans, ans, jnp.zeros(ans.shape, bool),
+                          bound=(lo, hi), staleness=staleness)
         bound = sess.budget(table).bound(sess.spec(table).agg)
         if hasattr(plan, "levels"):
             # LSM ladder: one AOT executable *per level*, fused exactly by
             # execute_lsm's combiner — a compaction only invalidates the
             # rebuilt slots' entries
-            res = execute_lsm(plan, buf, self._concat_ranges(grp),
-                              backend=sess.backend, eps_rel=rel,
-                              interpret=sess.interpret, bq=sess.bq,
-                              min_bucket=sess.min_bucket,
-                              level_runner=self._lsm_runner(
-                                  table, rel, size, plan))
-            jax.block_until_ready(res.answer)
-            self._scatter(grp, Answer(res.answer, res.approx, res.refined,
-                                      bound=bound, staleness=staleness),
-                          staleness)
-            return
-        compiled = self._executable(table, rel, size, plan, buf)
-        fills = pad_fills(plan)
-        dt = plan.dtype
-        qs = tuple(
-            _pad_bucket(jnp.asarray(c, dt), size,
-                        jnp.asarray(fills[j], dt))
-            for j, c in enumerate(self._concat_ranges(grp)))
-        ans, approx, refined = compiled(plan, buf, *qs)
-        jax.block_until_ready(ans)   # futures resolve device-ready
-        self._scatter(grp, Answer(ans, approx, refined, bound=bound,
-                                  staleness=staleness), staleness)
+            with spans.span("polyfit.serve.prepare"):
+                ranges = self._concat_ranges(grp)
+            with spans.span("polyfit.serve.execute"):
+                res = execute_lsm(plan, buf, ranges, backend=sess.backend,
+                                  eps_rel=rel, interpret=sess.interpret,
+                                  bq=sess.bq, min_bucket=sess.min_bucket,
+                                  level_runner=self._lsm_runner(
+                                      table, rel, size, plan))
+            return Answer(res.answer, res.approx, res.refined, bound=bound,
+                          staleness=staleness)
+        with spans.span("polyfit.aot.lookup"):
+            compiled = self._executable(table, rel, size, plan, buf)
+        with spans.span("polyfit.serve.prepare"):
+            fills = pad_fills(plan)
+            dt = plan.dtype
+            qs = tuple(
+                _pad_bucket(jnp.asarray(c, dt), size,
+                            jnp.asarray(fills[j], dt))
+                for j, c in enumerate(self._concat_ranges(grp)))
+        with spans.span("polyfit.serve.execute"):
+            ans, approx, refined = compiled(plan, buf, *qs)
+        return Answer(ans, approx, refined, bound=bound, staleness=staleness)
 
     @staticmethod
     def _concat_ranges(grp: List[_ReadRequest]) -> Tuple:
@@ -826,7 +863,7 @@ class ServingEngine:
                                        kind=kind)
             k = sess.spec(table).n_ranges if kind == "range" else 1
             qs = [jax.ShapeDtypeStruct((size,), plan.dtype)] * k
-            compiled = jax.jit(fn).lower(plan, buf, *qs).compile()
+            compiled = _aot_compile(fn, plan, buf, *qs)
             self._cache[key] = _ExecEntry(plan, compiled, sig=sig,
                                           buf_tmpl=_tree_tmpl(buf))
             with self._stats_lock:
@@ -852,7 +889,7 @@ class ServingEngine:
     def _lower_level(lvl, agg: str, statics: dict, size: int, k: int):
         fn = level_executor(agg, **statics)
         qs = [jax.ShapeDtypeStruct((size,), lvl.plan.dtype)] * k
-        return jax.jit(fn).lower(lvl, *qs).compile()
+        return _aot_compile(fn, lvl, *qs)
 
     def _level_executable(self, table: str, rel, size: int, lvl, agg: str,
                           statics: dict, k: int):
@@ -890,8 +927,10 @@ class ServingEngine:
         agg = lsm.agg
 
         def runner(i, lvl, *qs):
-            return self._level_executable(table, rel, size, lvl, agg,
-                                          statics, k)(lvl, *qs)
+            with spans.span("polyfit.aot.lookup"):
+                compiled = self._level_executable(table, rel, size, lvl, agg,
+                                                  statics, k)
+            return compiled(lvl, *qs)
         return runner
 
     # -- plan-swap pre-compilation (merge-thread listener) -----------------
@@ -971,7 +1010,7 @@ class ServingEngine:
                 tmpl = entry.buf_tmpl
             fn = sess.serving_executor(table, rel, bq=min(sess.bq, size))
             qs = [jax.ShapeDtypeStruct((size,), incoming.dtype)] * k
-            compiled = jax.jit(fn).lower(incoming, tmpl, *qs).compile()
+            compiled = _aot_compile(fn, incoming, tmpl, *qs)
             with self._compile_lock:
                 entry = self._cache.get(key)
                 if entry is not None:
@@ -990,7 +1029,7 @@ class ServingEngine:
             fn = sess.serving_executor(table, None, bq=min(sess.bq, size),
                                        kind="quantile")
             q = jax.ShapeDtypeStruct((size,), incoming.dtype)
-            compiled = jax.jit(fn).lower(incoming, tmpl, q).compile()
+            compiled = _aot_compile(fn, incoming, tmpl, q)
             with self._compile_lock:
                 entry = self._cache.get(key)
                 if entry is not None:
@@ -1186,7 +1225,6 @@ class ServingEngine:
                     # injected crash: leave the un-applied suffix in the
                     # journal and die through _updater_run
                     with self._stats_lock:
-                        self._stats.drains += 1
                         self._stats.fused_applies += applies
                     raise
                 except BaseException as e:
@@ -1201,7 +1239,6 @@ class ServingEngine:
                             it.future.set_exception(e)
                     continue
             with self._stats_lock:
-                self._stats.drains += 1
                 self._stats.fused_applies += applies
             return True
 
