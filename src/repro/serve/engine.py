@@ -7,15 +7,18 @@ engine with three moving parts:
 * **Bounded request queue + admission batching.**  ``submit`` enqueues a
   read and returns a future; background worker threads drain the queue,
   coalesce whatever is waiting (up to ``max_batch`` queries) into groups
-  keyed on (table, guarantee, deadline class), pad each group to its
+  keyed on (table, guarantee, deadline class), assemble each group's
+  ranges on the host as one ``(k, bucket)`` array padded to its
   power-of-two bucket, and answer every caller's future from one device
-  dispatch.  The executors are elementwise per query, so coalesced
-  answers are bit-identical to serial execution of the same requests.
+  dispatch: one transfer in, one host copy out (or the device arrays as
+  they are, for a lone request that fills its bucket).  The executors
+  are elementwise per query, so coalesced answers are bit-identical to
+  serial execution of the same requests.
   Admission is ``'block'`` (default: ``submit`` waits for room) or
   ``'reject'`` (``QueueFull`` when the queue is at capacity).
 
 * **AOT executable cache.**  Each (table, guarantee, bucket) is served by
-  a ``jax.jit(fn).lower(plan, buf, *qs).compile()`` executable, so the
+  a ``jax.jit(fn).lower(plan, buf, q).compile()`` executable, so the
   steady state never re-traces: admission batching maps every batch shape
   onto the cached bucket ladder.  Compiled objects pin the plan's static
   metadata (``delta``/``h``/``n`` change on every merge), so entries are
@@ -92,7 +95,6 @@ from concurrent.futures import Future
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from .. import spans
@@ -101,7 +103,7 @@ from ..api.spec import DEFAULT_REL, QueryBatch, QuerySpec
 from ..core.queries import QueryResult
 from ..dist.fault_tolerance import HeartbeatMonitor
 from ..engine import execute_lsm, level_executor, pad_fills
-from ..engine.engine import _bucket_size, _pad_bucket
+from ..engine.engine import _bucket_size
 
 __all__ = ["ServingEngine", "QueueFull", "Overloaded", "DeadlineExceeded",
            "EngineStats"]
@@ -144,6 +146,8 @@ class EngineStats:
     updater_crashes: int = 0  # updater threads that died mid-drain
     restarts: int = 0         # threads respawned by the supervisor
     journal_replayed: int = 0  # items a restarted updater found un-applied
+    scatter_host_copies: int = 0  # dispatches answered from one host copy
+    scatter_passthrough: int = 0  # lone full-bucket requests, no copy
 
 
 class _ReadRequest:
@@ -234,34 +238,42 @@ class _ExecEntry:
     tombstone array — an AOT executable pins those shapes).
     ``next_*`` hold the successor staged by the merge-thread
     pre-compilation listener; ``promote`` installs it at dispatch when
-    the incoming plan matches, so a swap costs zero relowers."""
+    the incoming plan matches, so a swap costs zero relowers.
+    ``fills`` (range and quantile entries) are the plan's padding values
+    on the host, read from the device once per plan."""
 
-    __slots__ = ("plan_ref", "compiled", "sig", "buf_tmpl",
-                 "next_ref", "next_compiled", "next_sig")
+    __slots__ = ("plan_ref", "compiled", "sig", "buf_tmpl", "fills",
+                 "next_ref", "next_compiled", "next_sig", "next_fills")
 
-    def __init__(self, plan_ref, compiled, sig=None, buf_tmpl=None):
+    def __init__(self, plan_ref, compiled, sig=None, buf_tmpl=None,
+                 fills=None):
         self.plan_ref = plan_ref    # identity-keyed: meta changes per swap
         self.compiled = compiled
         self.sig = sig
         self.buf_tmpl = buf_tmpl    # ShapeDtypeStruct pytree for relowers
+        self.fills = fills
         self.next_ref = None
         self.next_compiled = None
         self.next_sig = None
+        self.next_fills = None
 
     def matches(self, plan_ref, sig) -> bool:
         return self.plan_ref is plan_ref and self.sig == sig
 
-    def stage(self, plan_ref, compiled, sig) -> None:
+    def stage(self, plan_ref, compiled, sig, fills=None) -> None:
         self.next_ref = plan_ref
         self.next_compiled = compiled
         self.next_sig = sig
+        self.next_fills = fills
 
     def promote(self, plan_ref, sig) -> bool:
         if self.next_ref is plan_ref and self.next_sig == sig:
             self.plan_ref = self.next_ref
             self.compiled = self.next_compiled
             self.sig = self.next_sig
+            self.fills = self.next_fills
             self.next_ref = self.next_compiled = self.next_sig = None
+            self.next_fills = None
             return True
         return False
 
@@ -278,6 +290,23 @@ def _aot_compile(fn, *args):
     swap."""
     with spans.span("polyfit.aot.compile"):
         return jax.jit(fn).lower(*args).compile()
+
+
+def _stacked(executor, k: int):
+    """``executor(plan, buf, *qs)`` taking its ``k`` query coordinates as
+    the rows of one ``(k, bucket)`` operand, so a dispatch makes one
+    host-to-device transfer."""
+    def fn(plan, buf, q):   # a profile names the module ``jit_fn``
+        return executor(plan, buf, *(q[j] for j in range(k)))
+    return fn
+
+
+def _host_fills(plan, kind: str) -> np.ndarray:
+    """Each query coordinate's padding value as a host array in the plan's
+    dtype: the values ``execute_*`` pad with (quantiles pad with 0.5)."""
+    if kind == "quantile":
+        return np.full(1, 0.5, plan.dtype)
+    return np.asarray(jax.device_get(pad_fills(plan)), plan.dtype)
 
 
 def _tree_tmpl(x):
@@ -345,6 +374,7 @@ class ServingEngine:
         self._update_errors: List[BaseException] = []
         self._stop = threading.Event()
         self._shut_down = False
+        self._closing = False       # shutdown began: refuse new reads
         self._n_workers = int(workers)
         self._thread_lock = threading.Lock()
         self._workers: List[Optional[threading.Thread]] = []
@@ -408,6 +438,9 @@ class ServingEngine:
         hangs."""
         if self._shut_down:
             return
+        # reads submitted from here on are refused, so the drain below
+        # ends once what is queued now is answered
+        self._closing = True
         threads = self._threads
         if drain and threads:
             self._queue.join()
@@ -536,7 +569,7 @@ class ServingEngine:
         ``admission='block'`` waits up to ``timeout`` for queue room (then
         raises ``QueueFull``); ``'reject'`` raises immediately when full.
         """
-        if self._shut_down:
+        if self._shut_down or self._closing:
             raise RuntimeError("serving engine shut down")
         kind, rel, params = self.session.resolve_spec(spec)
         d_default, p_default = self._admission_class(spec.table)
@@ -717,10 +750,25 @@ class ServingEngine:
             with self._stats_lock:
                 self._stats.stale_reads += len(grp)
         res = self._execute(table, kind, rel, params, grp, staleness)
+        if not isinstance(res, Answer):  # degenerate paths (QueryResult)
+            res = Answer(res.answer, res.approx, res.refined,
+                         staleness=staleness)
+        # a lone request that fills the result takes the device arrays as
+        # they are; any other group is sliced from one host copy, so no
+        # eager device op (nor its compile) runs per request
+        whole = len(grp) == 1 and res.value.shape[0] == grp[0].n
         with spans.span("polyfit.serve.device_wait"):
-            jax.block_until_ready(res.answer)   # futures resolve device-ready
+            if whole:
+                jax.block_until_ready(res.value)  # resolve device-ready
+            else:
+                res = self._host_copy(res)
+        with self._stats_lock:
+            if whole:
+                self._stats.scatter_passthrough += 1
+            else:
+                self._stats.scatter_host_copies += 1
         with spans.span("polyfit.serve.scatter"):
-            self._scatter(grp, res, staleness)
+            self._scatter(grp, res, whole)
 
     def _execute(self, table: str, kind: str, rel, params: Tuple,
                  grp: List[_ReadRequest], staleness: int):
@@ -759,15 +807,13 @@ class ServingEngine:
         plan, buf = sess.snapshot(table)
         if kind == "quantile":
             with spans.span("polyfit.aot.lookup"):
-                compiled = self._executable(table, rel, size, plan, buf,
-                                            kind="quantile")
+                compiled, fills = self._executable(table, rel, size, plan,
+                                                   buf, kind="quantile")
             with spans.span("polyfit.serve.prepare"):
-                (qs,) = self._concat_ranges(grp)
-                qp = _pad_bucket(jnp.asarray(qs, plan.dtype), size,
-                                 jnp.asarray(0.5, plan.dtype))
+                q = self._stack_ranges(grp, size, fills)
             with spans.span("polyfit.serve.execute"):
-                ans, lo, hi = compiled(plan, buf, qp)
-            return Answer(ans, ans, jnp.zeros(ans.shape, bool),
+                ans, lo, hi = compiled(plan, buf, q)
+            return Answer(ans, ans, np.zeros(size, bool),
                           bound=(lo, hi), staleness=staleness)
         bound = sess.budget(table).bound(sess.spec(table).agg)
         if hasattr(plan, "levels"):
@@ -785,25 +831,35 @@ class ServingEngine:
             return Answer(res.answer, res.approx, res.refined, bound=bound,
                           staleness=staleness)
         with spans.span("polyfit.aot.lookup"):
-            compiled = self._executable(table, rel, size, plan, buf)
+            compiled, fills = self._executable(table, rel, size, plan, buf)
         with spans.span("polyfit.serve.prepare"):
-            fills = pad_fills(plan)
-            dt = plan.dtype
-            qs = tuple(
-                _pad_bucket(jnp.asarray(c, dt), size,
-                            jnp.asarray(fills[j], dt))
-                for j, c in enumerate(self._concat_ranges(grp)))
+            q = self._stack_ranges(grp, size, fills)
         with spans.span("polyfit.serve.execute"):
-            ans, approx, refined = compiled(plan, buf, *qs)
+            ans, approx, refined = compiled(plan, buf, q)
         return Answer(ans, approx, refined, bound=bound, staleness=staleness)
 
     @staticmethod
     def _concat_ranges(grp: List[_ReadRequest]) -> Tuple:
+        """The group's ranges per coordinate, as host arrays."""
         if len(grp) == 1:
             return tuple(grp[0].ranges)
         return tuple(
-            jnp.concatenate([jnp.asarray(r.ranges[j]) for r in grp])
+            np.concatenate([r.ranges[j] for r in grp])
             for j in range(len(grp[0].ranges)))
+
+    @classmethod
+    def _stack_ranges(cls, grp: List[_ReadRequest], size: int,
+                      fills: np.ndarray) -> np.ndarray:
+        """The group's query coordinates as the rows of one host
+        ``(k, size)`` array in the plan's dtype, each row's tail padded
+        with its coordinate's fill: the executable's one query operand."""
+        cols = cls._concat_ranges(grp)
+        n = len(cols[0])
+        q = np.empty((len(fills), size), fills.dtype)
+        q[:, n:] = fills[:, None]
+        for j, c in enumerate(cols):
+            q[j, :n] = c
+        return q
 
     @staticmethod
     def _slice_answer(a, off: int, m: int) -> "Answer":
@@ -815,19 +871,28 @@ class ServingEngine:
                       staleness=a.staleness)
 
     @staticmethod
-    def _scatter(grp: List[_ReadRequest], res, staleness: int = 0) -> None:
-        if not isinstance(res, Answer):  # degenerate paths (QueryResult)
-            res = Answer(res.answer, res.approx, res.refined,
-                         staleness=staleness)
+    def _host_copy(a: "Answer") -> "Answer":
+        """The answers (and quantile certificates) as host arrays, from one
+        ``device_get`` that starts every copy before it waits."""
+        quantile = isinstance(a.bound, tuple)
+        arrays = (a.value, a.approx, a.refined) + (a.bound if quantile
+                                                   else ())
+        got = jax.device_get(arrays)
+        return Answer(*got[:3], bound=tuple(got[3:]) if quantile else a.bound,
+                      staleness=a.staleness)
+
+    @staticmethod
+    def _scatter(grp: List[_ReadRequest], res: "Answer",
+                 whole: bool) -> None:
         off = 0
         for r in grp:
             m = r.n
             # per-answer degradation signal: how many acknowledged update
             # records were not yet applied when this answer was computed
-            r.future.staleness = staleness
+            r.future.staleness = res.staleness
             if not r.future.done():
                 r.future.set_result(
-                    ServingEngine._slice_answer(res, off, m))
+                    res if whole else ServingEngine._slice_answer(res, off, m))
             off += m
 
     # -- AOT executable cache ---------------------------------------------
@@ -844,31 +909,39 @@ class ServingEngine:
         if entry is not None and entry.matches(plan, sig):
             with self._stats_lock:
                 self._stats.aot_hits += 1
-            return entry.compiled
+            return entry.compiled, entry.fills
         with self._compile_lock:
             entry = self._cache.get(key)
             if entry is not None:
                 if entry.matches(plan, sig):
                     with self._stats_lock:
                         self._stats.aot_hits += 1
-                    return entry.compiled
+                    return entry.compiled, entry.fills
                 if entry.promote(plan, sig):
                     with self._stats_lock:
                         self._stats.aot_promotions += 1
-                    return entry.compiled
+                    return entry.compiled, entry.fills
                 with self._stats_lock:
                     self._stats.aot_invalidations += 1
-            sess = self.session
-            fn = sess.serving_executor(table, rel, bq=min(sess.bq, size),
-                                       kind=kind)
-            k = sess.spec(table).n_ranges if kind == "range" else 1
-            qs = [jax.ShapeDtypeStruct((size,), plan.dtype)] * k
-            compiled = _aot_compile(fn, plan, buf, *qs)
+            compiled = self._lower_stacked(table, rel, size, plan, buf, kind)
+            fills = _host_fills(plan, kind)
             self._cache[key] = _ExecEntry(plan, compiled, sig=sig,
-                                          buf_tmpl=_tree_tmpl(buf))
+                                          buf_tmpl=_tree_tmpl(buf),
+                                          fills=fills)
             with self._stats_lock:
                 self._stats.aot_compiles += 1
-            return compiled
+            return compiled, fills
+
+    def _lower_stacked(self, table: str, rel, size: int, plan, buf,
+                       kind: str):
+        """The table's serving executor for one bucket, lowered with the
+        stacked query signature ``(plan, buf, q[k, size])``."""
+        sess = self.session
+        fn = sess.serving_executor(table, rel, bq=min(sess.bq, size),
+                                   kind=kind)
+        k = sess.spec(table).n_ranges if kind == "range" else 1
+        q = jax.ShapeDtypeStruct((k, size), plan.dtype)
+        return _aot_compile(_stacked(fn, k), plan, buf, q)
 
     # -- LSM tables: per-level executables ---------------------------------
 
@@ -965,16 +1038,15 @@ class ServingEngine:
     def _precompile(self, table: str, incoming) -> None:
         sess = self.session
         with self._compile_lock:
-            combos = sorted({(key[1], key[2]) for key in self._cache
-                             if key[0] == table and len(key) == 3},
-                            key=lambda c: (repr(c[0]), c[1]))
+            # range keys (table, rel, size), then quantile keys
+            # (table, None, size, "quantile")
+            keys = sorted((key for key in self._cache if key[0] == table
+                           and (len(key) == 3 or key[3] == "quantile")),
+                          key=lambda c: (len(c), repr(c[1]), c[2]))
             lsm_combos = sorted({(key[1], key[2]) for key in self._cache
                                  if key[0] == table and len(key) == 4
                                  and key[3] != "quantile"},
                                 key=lambda c: (repr(c[0]), c[1]))
-            q_sizes = sorted({key[2] for key in self._cache
-                              if key[0] == table and len(key) == 4
-                              and key[3] == "quantile"})
         k = sess.spec(table).n_ranges
         if hasattr(incoming, "levels"):
             for rel, size in lsm_combos:
@@ -999,8 +1071,8 @@ class ServingEngine:
                     with self._stats_lock:
                         self._stats.aot_precompiles += 1
             return
-        for rel, size in combos:
-            key = (table, rel, size)
+        for key in keys:
+            kind = "range" if len(key) == 3 else "quantile"
             with self._compile_lock:
                 entry = self._cache.get(key)
                 if entry is None or entry.buf_tmpl is None \
@@ -1008,32 +1080,13 @@ class ServingEngine:
                         or entry.next_ref is incoming:
                     continue
                 tmpl = entry.buf_tmpl
-            fn = sess.serving_executor(table, rel, bq=min(sess.bq, size))
-            qs = [jax.ShapeDtypeStruct((size,), incoming.dtype)] * k
-            compiled = _aot_compile(fn, incoming, tmpl, *qs)
+            compiled = self._lower_stacked(table, key[1], key[2], incoming,
+                                           tmpl, kind)
+            fills = _host_fills(incoming, kind)
             with self._compile_lock:
                 entry = self._cache.get(key)
                 if entry is not None:
-                    entry.stage(incoming, compiled, _tree_sig(tmpl))
-            with self._stats_lock:
-                self._stats.aot_precompiles += 1
-        for size in q_sizes:
-            key = (table, None, size, "quantile")
-            with self._compile_lock:
-                entry = self._cache.get(key)
-                if entry is None or entry.buf_tmpl is None \
-                        or entry.plan_ref is incoming \
-                        or entry.next_ref is incoming:
-                    continue
-                tmpl = entry.buf_tmpl
-            fn = sess.serving_executor(table, None, bq=min(sess.bq, size),
-                                       kind="quantile")
-            q = jax.ShapeDtypeStruct((size,), incoming.dtype)
-            compiled = _aot_compile(fn, incoming, tmpl, q)
-            with self._compile_lock:
-                entry = self._cache.get(key)
-                if entry is not None:
-                    entry.stage(incoming, compiled, _tree_sig(tmpl))
+                    entry.stage(incoming, compiled, _tree_sig(tmpl), fills)
             with self._stats_lock:
                 self._stats.aot_precompiles += 1
 

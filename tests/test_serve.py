@@ -1,6 +1,8 @@
 """Serving-engine tests (serve/engine.py): coalesced answers bit-identical
 to serial execution, concurrent reader/writer pools, queue backpressure,
-AOT-cache plan-swap invalidation, and shutdown/drain semantics.
+AOT-cache plan-swap invalidation, shutdown/drain semantics, and the
+host-assembled admission path (no compile after warm-up, one host copy
+or a pass-through per dispatch).
 
 Everything runs backend='ref' on small synthetic tables so the suite
 stays CPU-cheap; the bit-identity assertions compare against the plain
@@ -13,9 +15,11 @@ from __future__ import annotations
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
+from repro import spans
 from repro.api import ErrorBudget, PolyFit, QuerySpec, TableSpec
 from repro.serve import QueueFull, ServingEngine
 
@@ -292,3 +296,180 @@ def test_update_normalization_errors(session):
         eng.delete("c2", np.array([1.0]), wait=False)    # ys missing
     with pytest.raises(RuntimeError):                    # static table
         eng.insert("min", np.array([1.0]), np.array([1.0]), wait=False)
+
+
+# -- host-assembled admission: one transfer in, one host copy out ----------
+
+@pytest.fixture(scope="module")
+def kinds_session():
+    """One table per admission case: 1-D COUNT (also quantiles), SUM and
+    MAX, 2-D COUNT, a dynamic SUM with buffered inserts, a windowed COUNT,
+    and a dynamic SUM kept for the plan-swap test."""
+    rng = np.random.default_rng(0xAD)
+    keys = np.sort(rng.uniform(0.0, 100.0, N1))
+    vals = rng.uniform(0.0, 10.0, N1)
+    xs, ys = rng.uniform(0.0, 50.0, N2), rng.uniform(0.0, 50.0, N2)
+    b = ErrorBudget(abs=50.0, rel=0.01)
+    dyn = dict(dynamic=True, capacity=256, auto_refit=False)
+    sess = PolyFit.fit(
+        {"cnt": keys, "sm": (keys, vals), "mx": (keys, vals),
+         "c2": (xs, ys), "dyn": (keys, vals), "w": (keys, None),
+         "swap": (keys, vals)},
+        {"cnt": TableSpec("count", b), "sm": TableSpec("sum", b),
+         "mx": TableSpec("max", ErrorBudget(abs=0.5, rel=0.01)),
+         "c2": TableSpec("count2d", b), "dyn": TableSpec("sum", b, **dyn),
+         "w": TableSpec("count", b, window=4),
+         "swap": TableSpec("sum", b, **dyn)},
+        backend="ref")
+    sess.insert("dyn", rng.uniform(0.0, 100.0, 40), np.full(40, 3.0))
+    sess.ingest("w", rng.uniform(0.0, 100.0, 200))
+    sess.advance_epoch("w")
+    return sess
+
+
+def _case_specs(case, rng, n):
+    """``n`` requests of 1-8 queries each on the case's table."""
+    specs = []
+    for m in rng.integers(1, 9, n):
+        lq = rng.uniform(0.0, 80.0, m)
+        if case == "quantile":
+            specs.append(QuerySpec.quantile("cnt", rng.uniform(0, 1, m)))
+        elif case == "count2d":
+            lx, ly = rng.uniform(0, 40, m), rng.uniform(0, 40, m)
+            specs.append(QuerySpec.rect("c2", lx, lx + 8, ly, ly + 8))
+        elif case == "window":
+            specs.append(QuerySpec.window("w", lq, lq + 10.0, 0, 1))
+        else:
+            table = {"count": "cnt", "sum": "sm", "max": "mx",
+                     "dynamic_sum": "dyn"}[case]
+            specs.append(QuerySpec.range(table, lq, lq + 10.0))
+    return specs
+
+
+@pytest.mark.parametrize("case", ["count", "sum", "max", "count2d",
+                                  "quantile", "dynamic_sum", "window"])
+def test_coalesced_bit_identical_to_serial_by_kind(kinds_session, case):
+    """Coalesced groups, assembled on the host and answered from one host
+    copy, give every request exactly its serial ``session.query`` answer,
+    certificates included."""
+    sess = kinds_session
+    specs = _case_specs(case, np.random.default_rng(11), 30)
+    serial = [sess.query(s) for s in specs]
+    eng = ServingEngine(sess, start=False)
+    futures = [eng.submit(s) for s in specs]   # all queued before serving
+    eng.start()
+    try:
+        for fut, want in zip(futures, serial):
+            got = fut.result(timeout=120)
+            _assert_identical(got, want)
+            assert np.array_equal(np.asarray(got.refined),
+                                  np.asarray(want.refined))
+            if case == "quantile":
+                for g, w in zip(got.bound, want.bound):
+                    assert np.array_equal(np.asarray(g), np.asarray(w))
+            else:
+                assert got.bound == want.bound
+        st = eng.stats
+        assert st.coalesced > 0 and st.dispatches < len(specs)
+        assert st.scatter_host_copies == st.dispatches
+    finally:
+        eng.shutdown()
+
+
+def test_warm_ladder_serves_without_compiling(kinds_session):
+    """After ``warmup(max_bucket=1024)``, coalesced requests of 1-8 ranges
+    over every bucket 64..1024, lone requests and full buckets trigger no
+    XLA compile: no eager device op runs between queue and future."""
+    sess = kinds_session
+    eng = ServingEngine(sess, max_batch=1024, start=False)
+    assert eng.warmup(max_bucket=1024, tables=["cnt", "c2"]) == 10
+    rng = np.random.default_rng(12)
+    waves = [_case_specs("count", rng, n) for n in (10, 20, 40, 100, 220)]
+    waves.append(_case_specs("count2d", rng, 60))
+    lone = [QuerySpec.range("cnt", rng.uniform(0, 50, n),
+                            rng.uniform(50, 100, n))
+            for n in (3, 64, 200, 1024)]
+    compiles = []
+
+    def listener(event, duration, **_):
+        if event == spans.COMPILE_EVENT:
+            compiles.append(event)
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        eng.start()
+        for wave in waves:   # queued while the worker answers the last
+            for f in [eng.submit(s) for s in wave]:
+                f.result(timeout=120)
+        for s in lone:
+            eng.query(s, timeout=120)
+    finally:
+        eng.shutdown()
+        jax.monitoring.unregister_event_duration_listener(listener)
+    st = eng.stats
+    assert st.coalesced > 0
+    assert st.answered == sum(map(len, waves)) + len(lone)
+    assert compiles == []
+    assert st.aot_compiles == 10
+
+
+def test_scatter_counts_passthrough_and_host_copies(kinds_session):
+    """A lone request that fills its bucket gets the executable's device
+    arrays as they are; a coalesced group, or a request smaller than its
+    bucket, is answered from one host copy as numpy views."""
+    sess = kinds_session
+    eng = ServingEngine(sess, start=False)
+    try:
+        eng.warmup(max_bucket=64, tables=["cnt"])
+        lq = np.linspace(0.0, 60.0, 64)
+        full = QuerySpec.range("cnt", lq, lq + 5.0)
+        group = [QuerySpec.range("cnt", lq[:m], lq[:m] + 5.0)
+                 for m in (1, 5, 8)]
+        futures = [eng.submit(s) for s in group]
+        eng.start()
+        answers = [f.result(timeout=120) for f in futures]
+        st = eng.stats
+        assert (st.dispatches, st.scatter_host_copies,
+                st.scatter_passthrough) == (1, 1, 0)
+        for a, s in zip(answers, group):
+            assert isinstance(a.value, np.ndarray)
+            assert a.value.shape == (len(s),)
+            _assert_identical(a, sess.query(s))
+        whole = eng.query(full, timeout=120)
+        assert eng.stats.scatter_passthrough == 1
+        assert isinstance(whole.value, jax.Array)
+        _assert_identical(whole, sess.query(full))
+        eng.query(group[1], timeout=120)    # 5 queries in a 64 bucket
+        st = eng.stats
+        assert (st.scatter_host_copies, st.scatter_passthrough) == (2, 1)
+    finally:
+        eng.shutdown()
+
+
+def test_plan_swap_promotes_stacked_executables(kinds_session):
+    """A merge on a dynamic table stages the incoming plan's range and
+    quantile executables in the stacked ``(plan, buf, q[k, bucket])``
+    signature; after the swap every dispatch promotes one, with no
+    relower, and answers equal the session's on the new plan."""
+    sess = kinds_session
+    eng = ServingEngine(sess)
+    rng = np.random.default_rng(13)
+    lq = rng.uniform(0.0, 80.0, 6)
+    rng_spec = QuerySpec.range("swap", lq, lq + 10.0)
+    q_spec = QuerySpec.quantile("swap", rng.uniform(0.0, 1.0, 5))
+    try:
+        assert eng.warmup(max_bucket=128, tables=["swap"],
+                          kinds=("range", "quantile")) == 4
+        eng.insert("swap", rng.uniform(0.0, 100.0, 30), np.full(30, 2.0),
+                   wait=True)
+        c0 = eng.stats.aot_compiles
+        eng.flush("swap")                        # merge -> plan swap
+        assert eng.stats.aot_precompiles == 4    # both ladders staged
+        got = [eng.query(rng_spec, timeout=120),
+               eng.query(q_spec, timeout=120)]
+        st = eng.stats
+        assert st.aot_compiles == c0             # no relower post-swap
+        assert st.aot_promotions == 2            # range and quantile
+        for g, s in zip(got, (rng_spec, q_spec)):
+            _assert_identical(g, sess.query(s))
+    finally:
+        eng.shutdown()
