@@ -36,7 +36,7 @@ from .dynamic import (DeltaBuffer, DeltaBuffer2D, DynamicEngine,
 from .engine import (BACKENDS, Engine, QuantileResult, execute,
                      execute_count2d, execute_extremum, execute_extremum2d,
                      execute_quantile, execute_sum, execute_sum2d,
-                     pad_fills)
+                     pad_fills, refine_slots)
 from .lsm import (CompactionPolicy, LsmEngine, LsmEngine2D, LsmLevel,
                   LsmLevel2D, LsmPlan, LsmPlan2D, composed_bound,
                   execute_lsm, level_executor)
@@ -53,7 +53,7 @@ __all__ = ["Engine", "BACKENDS", "IndexPlan", "IndexPlan2D", "build_plan",
            "build_plan_2d", "big_sentinel", "pad_to_multiple",
            "DynamicEngine", "DynamicEngine2D", "DeltaBuffer",
            "DeltaBuffer2D", "fused_executor", "fused_quantile_executor",
-           "pad_fills", "execute", "execute_sum", "execute_extremum",
+           "pad_fills", "refine_slots", "execute", "execute_sum", "execute_extremum",
            "execute_quantile", "QuantileResult", "WindowEngine",
            "execute_count2d", "execute_sum2d", "execute_extremum2d",
            "LsmEngine", "LsmEngine2D", "LsmPlan", "LsmPlan2D", "LsmLevel",

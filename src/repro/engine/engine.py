@@ -20,10 +20,12 @@ Every path is a single jitted function that computes the raw approximation,
 applies the Lemma 5.2/5.4 (or 6.4) Q_rel acceptance test, and merges the
 vectorized exact refinement with ``jnp.where`` — the refinement arrays live
 inside the plan, so there is no host round trip and no per-query Python
-dispatch.  Batches are padded to power-of-two buckets before entering the
-jitted path: compilation count is bounded by the number of distinct
-(aggregate, backend, bucket) triples, and plans with identical layouts share
-compilations (plan metadata is static, arrays are traced).
+dispatch.  SUM/COUNT runs the exact search on the flagged rows alone when
+they fit in ``refine_slots`` (``_refine_sum``).  Batches are padded to
+power-of-two buckets before entering the jitted path: compilation count
+is bounded by the number of distinct (aggregate, backend, bucket)
+triples, and plans with identical layouts share compilations (plan
+metadata is static, arrays are traced).
 
 Q_abs guarantees need no test: build the index with delta = eps_abs/2 (SUM,
 Lemma 5.1), eps_abs (MAX, Lemma 5.3) or eps_abs/4 (2-D COUNT, Lemma 6.3)
@@ -59,7 +61,8 @@ __all__ = ["Engine", "BACKENDS", "QuantileResult", "raw_sum",
            "truth_extremum", "truth_count2d", "truth_sum2d",
            "truth_dommax2d", "check_pow2", "execute_sum",
            "execute_extremum", "execute_quantile", "execute_count2d",
-           "execute_sum2d", "execute_extremum2d", "execute", "pad_fills"]
+           "execute_sum2d", "execute_extremum2d", "execute", "pad_fills",
+           "refine_slots"]
 
 
 class QuantileResult(NamedTuple):
@@ -261,8 +264,46 @@ def _exec_sum(plan: IndexPlan, lq, uq, *, backend: str,
     ok = ((approx - two_d > 0) &
           (two_d / jnp.maximum(approx - two_d, 1e-300) <= eps_rel))
     with jax.named_scope("refine"):
-        truth = truth_sum(plan, lq, uq)
-    return jnp.where(ok, approx, truth), approx, ~ok
+        ans = _refine_sum(plan, lq, uq, approx, ok)
+    return ans, approx, ~ok
+
+
+def refine_slots(size: int) -> int:
+    """Rows of a ``size``-query bucket that ``_exec_sum`` runs the exact
+    refinement search on: a sixteenth of the bucket, at least 8.  Served
+    batches flag about 2% of their queries, so the slots hold every
+    flagged row of a 1024 bucket (64 slots) or a 64 bucket (8) with room
+    to spare; a batch that flags more searches every row."""
+    return max(8, size // 16)
+
+
+def _refine_sum(plan: IndexPlan, lq, uq, approx, ok):
+    """``where(ok, approx, truth_sum(plan, lq, uq))`` bit for bit, with the
+    exact search run only on the rows that need it when they fit in
+    ``refine_slots``.  A flagged row with ``lq == uq`` needs none: its
+    truth is ``cf - cf = +0.0`` (padding rows are such rows)."""
+    size = lq.shape[0]
+    k = refine_slots(size)
+
+    def full():
+        return jnp.where(ok, approx, truth_sum(plan, lq, uq))
+
+    if k >= size:
+        return full()
+    need = ~ok & (lq != uq)
+    slot = jnp.cumsum(need, dtype=jnp.int32) - 1   # a needed row's slot
+
+    def compact():
+        # the row of slot j is the number of rows before the (j+1)-th
+        # needed one; an empty slot searches the last row, unused
+        j = jnp.arange(k, dtype=jnp.int32)
+        rows = jnp.minimum(jnp.sum(slot[None, :] < j[:, None], axis=1),
+                           size - 1)
+        truth = truth_sum(plan, lq[rows], uq[rows])
+        return jnp.where(need, jnp.take(truth, slot, mode="clip"),
+                         jnp.where(ok, approx, 0.0))
+
+    return jax.lax.cond(slot[-1] < k, compact, full)
 
 
 @partial(jax.jit, static_argnames=("backend", "eps_rel", "interpret", "bq"))

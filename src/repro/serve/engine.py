@@ -102,7 +102,7 @@ from ..api.session import Answer
 from ..api.spec import DEFAULT_REL, QueryBatch, QuerySpec
 from ..core.queries import QueryResult
 from ..dist.fault_tolerance import HeartbeatMonitor
-from ..engine import execute_lsm, level_executor, pad_fills
+from ..engine import execute_lsm, level_executor, pad_fills, refine_slots
 from ..engine.engine import _bucket_size
 
 __all__ = ["ServingEngine", "QueueFull", "Overloaded", "DeadlineExceeded",
@@ -148,6 +148,8 @@ class EngineStats:
     journal_replayed: int = 0  # items a restarted updater found un-applied
     scatter_host_copies: int = 0  # dispatches answered from one host copy
     scatter_passthrough: int = 0  # lone full-bucket requests, no copy
+    refine_overflows: int = 0  # static SUM/COUNT dispatches that refined
+    #                            every row (pass-through: with spans on)
 
 
 class _ReadRequest:
@@ -282,6 +284,14 @@ def _tree_sig(x) -> Tuple:
     """Hashable (structure, shapes, dtypes) signature of a pytree."""
     leaves, treedef = jax.tree_util.tree_flatten(x)
     return treedef, tuple((l.shape, str(l.dtype)) for l in leaves)
+
+
+def _refine_overflowed(q: np.ndarray, refined: np.ndarray) -> bool:
+    """Whether a static SUM/COUNT dispatch of stacked ranges ``q`` (its
+    ``(lq, uq)`` rows) refined every row: more rows were flagged with
+    ``lq != uq`` than ``refine_slots`` holds, the executor's own test."""
+    need = np.count_nonzero(refined & (q[0] != q[1]))
+    return need > refine_slots(q.shape[1])
 
 
 def _aot_compile(fn, *args):
@@ -749,7 +759,7 @@ class ServingEngine:
         if staleness:
             with self._stats_lock:
                 self._stats.stale_reads += len(grp)
-        res = self._execute(table, kind, rel, params, grp, staleness)
+        res, q = self._execute(table, kind, rel, params, grp, staleness)
         if not isinstance(res, Answer):  # degenerate paths (QueryResult)
             res = Answer(res.answer, res.approx, res.refined,
                          staleness=staleness)
@@ -762,18 +772,27 @@ class ServingEngine:
                 jax.block_until_ready(res.value)  # resolve device-ready
             else:
                 res = self._host_copy(res)
+        overflowed = False
+        if q is not None and not whole:
+            overflowed = _refine_overflowed(q, res.refined)
+        elif q is not None and spans.enabled():
+            with spans.span("polyfit.serve.refine_count"):
+                overflowed = _refine_overflowed(q, np.asarray(res.refined))
         with self._stats_lock:
             if whole:
                 self._stats.scatter_passthrough += 1
             else:
                 self._stats.scatter_host_copies += 1
+            self._stats.refine_overflows += overflowed
         with spans.span("polyfit.serve.scatter"):
             self._scatter(grp, res, whole)
 
     def _execute(self, table: str, kind: str, rel, params: Tuple,
                  grp: List[_ReadRequest], staleness: int):
         """Start one group's device work; returns its answers, which may
-        not be ready yet."""
+        not be ready yet, and the stacked ranges where the executable
+        compacts its refinement (static SUM/COUNT under Q_rel), else
+        None."""
         sess = self.session
         nq = sum(r.n for r in grp)
         size = _bucket_size(nq, sess.min_bucket)
@@ -789,21 +808,21 @@ class ServingEngine:
                 if plan is None:
                     return sess.query(QuerySpec(table, ranges, rel,
                                                 kind="window",
-                                                params=params))
+                                                params=params)), None
                 res = execute_lsm(plan, buf, ranges, backend=sess.backend,
                                   eps_rel=rel, interpret=sess.interpret,
                                   bq=sess.bq, min_bucket=sess.min_bucket,
                                   level_runner=self._lsm_runner(
                                       table, rel, size, plan))
             return Answer(res.answer, res.approx, res.refined, bound=bound,
-                          staleness=staleness)
+                          staleness=staleness), None
         if sess.is_sharded(table):
             # shard_map executors keep their own cache; no AOT ladder here
             with spans.span("polyfit.serve.prepare"):
                 ranges = self._concat_ranges(grp)
             with spans.span("polyfit.serve.execute"):
                 return sess.query(QuerySpec(table, ranges, rel, kind=kind,
-                                            params=params))
+                                            params=params)), None
         plan, buf = sess.snapshot(table)
         if kind == "quantile":
             with spans.span("polyfit.aot.lookup"):
@@ -814,8 +833,9 @@ class ServingEngine:
             with spans.span("polyfit.serve.execute"):
                 ans, lo, hi = compiled(plan, buf, q)
             return Answer(ans, ans, np.zeros(size, bool),
-                          bound=(lo, hi), staleness=staleness)
-        bound = sess.budget(table).bound(sess.spec(table).agg)
+                          bound=(lo, hi), staleness=staleness), None
+        spec = sess.spec(table)
+        bound = sess.budget(table).bound(spec.agg)
         if hasattr(plan, "levels"):
             # LSM ladder: one AOT executable *per level*, fused exactly by
             # execute_lsm's combiner — a compaction only invalidates the
@@ -829,14 +849,17 @@ class ServingEngine:
                                   level_runner=self._lsm_runner(
                                       table, rel, size, plan))
             return Answer(res.answer, res.approx, res.refined, bound=bound,
-                          staleness=staleness)
+                          staleness=staleness), None
         with spans.span("polyfit.aot.lookup"):
             compiled, fills = self._executable(table, rel, size, plan, buf)
         with spans.span("polyfit.serve.prepare"):
             q = self._stack_ranges(grp, size, fills)
         with spans.span("polyfit.serve.execute"):
             ans, approx, refined = compiled(plan, buf, q)
-        return Answer(ans, approx, refined, bound=bound, staleness=staleness)
+        compacts = (rel is not None and not spec.dynamic
+                    and spec.agg in ("sum", "count"))
+        return (Answer(ans, approx, refined, bound=bound,
+                       staleness=staleness), q if compacts else None)
 
     @staticmethod
     def _concat_ranges(grp: List[_ReadRequest]) -> Tuple:

@@ -11,7 +11,9 @@ jax.config.update("jax_enable_x64", True)
 from repro.core import (ExactMax, ExactSum, build_index_1d,  # noqa: E402
                         build_index_2d, query_count_2d, query_max, query_sum)
 from repro.engine import (BACKENDS, Engine, build_plan,  # noqa: E402
-                          build_plan_2d)
+                          build_plan_2d, refine_slots)
+import repro.engine.engine as engine_mod  # noqa: E402
+from repro.engine.engine import _exec_sum, raw_sum, truth_sum  # noqa: E402
 
 N = 3000
 NQ = 400
@@ -192,6 +194,99 @@ def test_serve_step_routes_through_engine(plans, queries):
     res = step(lq, uq)
     assert res.answer.shape == (NQ,)
     assert np.asarray(res.refined).mean() < 1.0
+
+
+# ---------------------------------------------------------------------------
+# compacted Q_rel refinement in the static SUM/COUNT executor
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def fine_count(data):
+    """A COUNT plan fine enough that wide ranges pass the Lemma 5.2 test
+    (2 * delta * (1 + 1/0.01) = 404 keys) and narrow ones are flagged."""
+    keys, _ = data
+    return keys, build_plan(build_index_1d(keys, None, "count", deg=2,
+                                           delta=2.0))
+
+
+@jax.jit
+def _whole_batch_sum(plan, lq, uq):
+    """The executor's refinement before compaction: the exact search on
+    every row of the bucket, kept where the Lemma 5.2 test fails."""
+    dt = plan.dtype
+    lqc = jnp.maximum(lq.astype(dt), plan.domain_lo)
+    uqc = jnp.maximum(uq.astype(dt), plan.domain_lo)
+    approx = raw_sum(plan, lqc, uqc, backend="xla", interpret=None, bq=256)
+    two_d = 2.0 * plan.delta
+    ok = ((approx - two_d > 0) &
+          (two_d / jnp.maximum(approx - two_d, 1e-300) <= 0.01))
+    return jnp.where(ok, approx, truth_sum(plan, lq, uq)), approx, ~ok
+
+
+def _flagged_bucket(keys, domain_lo, size, m, rng):
+    """``size`` shuffled rows, ``m`` of them flagged with ``lq != uq``
+    (narrow ranges and reversed ones, ``lq > uq``); the rest are wide
+    ranges that pass, padding rows (``lq == uq == domain_lo``) and empty
+    ranges ``lq == uq`` inside the domain, both flagged but searched by
+    neither path."""
+    i = rng.integers(0, N - 1100, size)
+    narrow = (keys[i], keys[i + 3])
+    kinds = np.where(np.arange(size) < m, np.arange(size) % 2,
+                     2 + np.arange(size) % 3)
+    lq = np.select([kinds == 0, kinds == 1, kinds == 2, kinds == 3],
+                   [narrow[0], narrow[1], keys[i], domain_lo], keys[i])
+    uq = np.select([kinds == 0, kinds == 1, kinds == 2, kinds == 3],
+                   [narrow[1], narrow[0], keys[i + 1000], domain_lo],
+                   keys[i])
+    perm = rng.permutation(size)
+    return lq[perm], uq[perm]
+
+
+@pytest.mark.parametrize("size", [64, 1024])
+@pytest.mark.parametrize("flagged", ["none", "one", "slots", "slots+1",
+                                     "all"])
+def test_compacted_refinement_bit_identical(fine_count, size, flagged,
+                                           monkeypatch):
+    """Refining only the flagged rows, in ``refine_slots`` slots or on the
+    whole bucket when they overflow, gives the whole-batch formula's
+    answers, approximations and flags bit for bit; the search runs on the
+    slots unless the flagged non-empty ranges outnumber them."""
+    keys, plan = fine_count
+    k = refine_slots(size)
+    m = {"none": 0, "one": 1, "slots": k, "slots+1": k + 1,
+         "all": size}[flagged]
+    lq, uq = _flagged_bucket(keys, float(plan.domain_lo), size, m,
+                             np.random.default_rng(size + m))
+    got = _exec_sum(plan, jnp.asarray(lq), jnp.asarray(uq), backend="xla",
+                    eps_rel=0.01, interpret=None, bq=256)
+    want = _whole_batch_sum(plan, jnp.asarray(lq), jnp.asarray(uq))
+    refined = np.asarray(got[2])
+    assert np.count_nonzero(refined & (lq != uq)) == m
+    if m < size:
+        assert (~refined).any() and (refined & (lq == uq)).any()
+    for g, w in zip(got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype
+        assert np.array_equal(g.view(np.uint8), w.view(np.uint8))
+    searched = []
+
+    def spy(plan, lq, uq):   # records the rows of the search that ran
+        jax.debug.callback(lambda: searched.append(lq.shape[0]))
+        return truth_sum(plan, lq, uq)
+    monkeypatch.setattr(engine_mod, "truth_sum", spy)
+    jax.block_until_ready(jax.jit(lambda *a: engine_mod._refine_sum(*a))(
+        plan, jnp.asarray(lq), jnp.asarray(uq), want[1], ~want[2]))
+    jax.effects_barrier()
+    assert searched == [k if m <= k else size]
+
+
+@pytest.mark.parametrize("size", [64, 128, 256, 512, 1024])
+def test_refine_slots_from_the_bucket_alone(size):
+    """A sixteenth of the bucket, at least 8: fewer slots than rows at
+    every served bucket, so every one compacts."""
+    k = refine_slots(size)
+    assert k == max(8, size // 16)
+    assert 8 <= k < size and size % k == 0
 
 
 # ---------------------------------------------------------------------------

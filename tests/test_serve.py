@@ -12,6 +12,7 @@ change a single bit).
 """
 from __future__ import annotations
 
+import re
 import threading
 import time
 
@@ -21,6 +22,7 @@ import pytest
 
 from repro import spans
 from repro.api import ErrorBudget, PolyFit, QuerySpec, TableSpec
+from repro.engine import refine_slots
 from repro.serve import QueueFull, ServingEngine
 
 N1 = 4000
@@ -443,6 +445,101 @@ def test_scatter_counts_passthrough_and_host_copies(kinds_session):
         assert (st.scatter_host_copies, st.scatter_passthrough) == (2, 1)
     finally:
         eng.shutdown()
+
+
+def test_refine_overflows_counted_from_host_copies(kinds_session):
+    """A host-copy dispatch whose flagged non-empty ranges outnumber
+    ``refine_slots`` counts one overflow; fewer flagged ranges, or flagged
+    rows with ``lq == uq`` only (padding among them), count none.  Every
+    answer is the session's, bit for bit."""
+    sess = kinds_session
+    eng = ServingEngine(sess, start=False)
+    try:
+        eng.warmup(max_bucket=64, tables=["cnt"])
+        assert refine_slots(64) == 8
+        lq = np.linspace(1.0, 60.0, 40)
+        specs = [QuerySpec.range("cnt", lq[:5], lq[:5] + 5.0),   # 5 <= 8
+                 QuerySpec.range("cnt", lq[:20], lq[:20]),       # empty
+                 QuerySpec.range("cnt", lq, lq + 5.0)]           # 40 > 8
+        overflows = []
+        eng.start()
+        for s in specs:
+            got = eng.query(s, timeout=120)
+            want = sess.query(s)
+            _assert_identical(got, want)
+            assert np.array_equal(got.refined, np.asarray(want.refined))
+            assert got.refined.all()   # every row flagged at this budget
+            overflows.append(eng.stats.refine_overflows)
+        assert overflows == [0, 0, 1]
+        futures = [eng.submit(QuerySpec.range("cnt", lq[:m], lq[:m] + 5.0))
+                   for m in (2, 3)]
+        for f in futures:
+            f.result(timeout=120)
+        assert eng.stats.refine_overflows == 1
+        assert eng.stats.scatter_host_copies == eng.stats.dispatches
+    finally:
+        eng.shutdown()
+
+
+def test_refine_overflows_on_passthrough_only_with_spans(kinds_session):
+    """A lone full 1024 request is answered correctly either way; its
+    overflow is counted, with one copy of the flags in its own span, only
+    while spans are enabled, so the serving path adds no transfer when
+    they are off."""
+    sess = kinds_session
+    eng = ServingEngine(sess, max_batch=1024, start=False)
+    rng = np.random.default_rng(17)
+    lq = rng.uniform(0.0, 90.0, 1024)
+    full = QuerySpec.range("cnt", lq, lq + 5.0)          # 1024 flagged
+    few = np.where(np.arange(1024) < 30, lq + 5.0, lq)   # 30 flagged
+    compact = QuerySpec.range("cnt", lq, few)
+    try:
+        eng.warmup(max_bucket=1024, tables=["cnt"])
+        eng.start()
+        for s in (full, compact):
+            _assert_identical(eng.query(s, timeout=120), sess.query(s))
+        assert eng.stats.scatter_passthrough == 2
+        assert eng.stats.refine_overflows == 0
+        spans.enable()
+        try:
+            for s in (full, compact):
+                _assert_identical(eng.query(s, timeout=120), sess.query(s))
+            snap = spans.snapshot()
+        finally:
+            spans.disable()
+        assert eng.stats.refine_overflows == 1
+        assert snap["by_name"]["polyfit.serve.refine_count"]["count"] == 2
+    finally:
+        eng.shutdown()
+
+
+def test_refine_scope_searches_the_slots(kinds_session):
+    """The bucket-1024 serving executable keeps its exact searches under
+    the ``refine`` scope, where ``bench/spans.py`` attributes device time:
+    one pair of ``while`` loops on the 64 slots, one on the whole bucket
+    for batches that overflow them."""
+    from bench.spans import hlo_scopes
+    sess = kinds_session
+    eng = ServingEngine(sess, max_batch=1024, start=False)
+    try:
+        eng.warmup(max_bucket=1024, tables=["cnt"])
+        rel = sess.resolve_rel("cnt")
+        text = eng._cache[("cnt", rel, 1024)].compiled.as_text()
+    finally:
+        eng.shutdown()
+    scopes = hlo_scopes([text])
+    shapes = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%?(while[\w.\-]*)\s*=\s*\((.*?)\)"
+                     r"\s*while\(", line)
+        if m:
+            shapes[m.group(1)] = m.group(2)
+    refine = [n for n in shapes if scopes.get(n) == "refine"]
+    assert len(refine) == 4
+    assert sorted("[64]" in shapes[n] for n in refine) == [False, False,
+                                                           True, True]
+    assert all("[1024]" in shapes[n] for n in refine
+               if "[64]" not in shapes[n])
 
 
 def test_plan_swap_promotes_stacked_executables(kinds_session):
